@@ -144,7 +144,6 @@ def _delta_ratio(cfg, prompt_len: int, *, impl=None, seed=2,
   import jax.numpy as jnp
   import numpy as np
 
-  from repro.models import common as cm
   from repro.models import transformer as tf
   from repro.serve import synopsis_kv as skv
   from repro.serve.prefill import make_extend_step, make_prefill_step
@@ -153,8 +152,7 @@ def _delta_ratio(cfg, prompt_len: int, *, impl=None, seed=2,
   # the balanced-kd clustering requires.
   E = prompt_len // 2
   P = prompt_len - E
-  params, _ = cm.split(tf.init_model(jax.random.PRNGKey(seed), cfg))
-  params = jax.tree.map(lambda p: p.astype(cfg.dtype), params)
+  params = tf.init_params(jax.random.PRNGKey(seed), cfg)
   rng = np.random.default_rng(seed)
   toks = jnp.asarray(rng.integers(0, cfg.vocab, (1, prompt_len)), jnp.int32)
   prefill = jax.jit(make_prefill_step(cfg, impl=impl))

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config
-from repro.models import common as cm
 from repro.models import transformer as tf
 from repro.serve import synopsis_kv as skv
 from repro.serve.kv_cache import n_attn_positions
@@ -38,8 +37,7 @@ def main():
   cfg = get_config(args.arch, smoke=True)
   assert n_attn_positions(cfg) > 0, "synopsis attention needs attention"
   key = jax.random.PRNGKey(0)
-  params, _ = cm.split(tf.init_model(key, cfg))
-  params = jax.tree.map(lambda p: p.astype(cfg.dtype), params)
+  params = tf.init_params(key, cfg)
 
   B, S = args.batch, args.seq
   prompt = jax.random.randint(key, (B, S), 0, cfg.vocab)

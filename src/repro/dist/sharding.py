@@ -189,33 +189,6 @@ def _strip_manual(target: AxisRule, manual: frozenset) -> AxisRule:
   return axes[0] if len(axes) == 1 else axes
 
 
-def supports_partial_manual() -> bool:
-  """Partial-manual shard_map (manual over a subset of mesh axes, GSPMD on
-  the rest) hits an XLA partitioner CHECK on the legacy
-  ``jax.experimental.shard_map`` builds; native ``jax.shard_map`` is the
-  capability marker for a working implementation."""
-  return hasattr(jax, "shard_map")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = False):
-  """``jax.shard_map`` compat shim: new API when available, else the
-  ``jax.experimental.shard_map`` spelling (axis_names -> auto complement,
-  check_vma -> check_rep)."""
-  if hasattr(jax, "shard_map"):
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names=axis_names,
-                         check_vma=check_vma)
-  from jax.experimental.shard_map import shard_map as _sm  # noqa: PLC0415
-  kwargs: Dict[str, Any] = {"check_rep": check_vma}
-  if axis_names is not None:
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    if auto:
-      kwargs["auto"] = auto
-  return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-             **kwargs)
-
-
 def constrain(x, logical_axes, rules: Optional[Dict[str, AxisRule]] = None):
   """``with_sharding_constraint`` by logical axis names.  No-op without an
   installed mesh, and manual (shard_map) axes are stripped first."""

@@ -29,10 +29,11 @@ holder is predicted to finish first.
 
 Mesh construction is a FUNCTION (like launch/mesh.py) so importing this
 module never touches jax device state: :func:`make_component_mesh` returns
-a 1-axis ``("component",)`` mesh when enough devices exist, else ``None``
-— the tier then falls back to the stacked single-device execution of the
-same math.  :func:`make_fleet_mesh` is the 2-axis
-``("replica", "component")`` counterpart over R*N devices.
+a 1-axis ``("component",)`` mesh and :func:`make_fleet_mesh` the 2-axis
+``("replica", "component")`` mesh over R*N devices.  ``None`` means the
+stacked single-device execution of the same math: always when asked for
+(``use_mesh=False``), and on the CPU backend when it has too few devices.
+On an accelerator too few devices is an error, never a silent stacked run.
 """
 from __future__ import annotations
 
@@ -202,30 +203,48 @@ def force_host_devices(n: int) -> None:
         f"{flags} --xla_force_host_platform_device_count={int(n)}").strip()
 
 
-def make_component_mesh(n_components: int):
-  """1-axis ``("component",)`` mesh over the first ``n`` local devices, or
-  ``None`` when the host has fewer devices (the tier then runs the stacked
-  fallback).  Deferred jax import keeps module import device-free."""
+class MeshUnavailable(RuntimeError):
+  """A tier asked for a mesh the host cannot build."""
+
+
+def _tier_mesh(shape: Tuple[int, ...], names: Tuple[str, ...],
+               use_mesh: Optional[bool], what: str):
+  """The tier's mesh over the first prod(shape) local devices, or ``None``
+  for its stacked execution (see the module docstring for when)."""
   import jax  # noqa: PLC0415 — deferred so module import is device-free
   from jax.sharding import Mesh  # noqa: PLC0415
-  devs = jax.devices()
-  if len(devs) < n_components:
+  if use_mesh is False:
     return None
-  return Mesh(np.array(devs[:n_components]), ("component",))
+  need = int(np.prod(shape))
+  devs = jax.devices()
+  if len(devs) >= need:
+    return Mesh(np.array(devs[:need]).reshape(shape), names)
+  backend = jax.default_backend()
+  if use_mesh or backend != "cpu":
+    hint = (f"; on CPU run under XLA_FLAGS=--xla_force_host_platform_device"
+            f"_count={need}" if backend == "cpu" else "")
+    raise MeshUnavailable(
+        f"{what} needs {need} devices for its mesh, found {len(devs)} "
+        f"{backend} device(s); pass use_mesh=False for the stacked "
+        f"single-device execution{hint}")
+  return None
 
 
-def make_fleet_mesh(n_components: int, replicas: int):
+def make_component_mesh(n_components: int,
+                        use_mesh: Optional[bool] = None):
+  """1-axis ``("component",)`` mesh over the first ``n`` local devices
+  (``None``: stacked execution, see :func:`_tier_mesh`)."""
+  n = int(n_components)
+  return _tier_mesh((n,), ("component",), use_mesh,
+                    f"component tier N={n}")
+
+
+def make_fleet_mesh(n_components: int, replicas: int,
+                    use_mesh: Optional[bool] = None):
   """2-axis ``("replica", "component")`` mesh over the first R*N local
   devices — replica rows are the *leading* mesh axis so a row is a
   contiguous device group (one host group per replica row on real
-  multi-host fleets).  Returns ``None`` when the host has fewer than
-  R*N devices; the fleet tier then runs the stacked fallback of the
-  same math."""
-  import jax  # noqa: PLC0415 — deferred so module import is device-free
-  from jax.sharding import Mesh  # noqa: PLC0415
+  multi-host fleets).  ``None``: stacked execution of the same math."""
   n, r = int(n_components), int(replicas)
-  devs = jax.devices()
-  if len(devs) < r * n:
-    return None
-  grid = np.array(devs[: r * n]).reshape(r, n)
-  return Mesh(grid, ("replica", "component"))
+  return _tier_mesh((r, n), ("replica", "component"), use_mesh,
+                    f"fleet tier R={r} x N={n}")

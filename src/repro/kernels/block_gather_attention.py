@@ -16,9 +16,10 @@ Fused epilogue (the serving path) — two optional extensions run inside the
 same grid/scratch, eliminating the separate ``_merge`` passes the serve
 step used to do:
 
-  * **decrement** ``(k_sel, v_sel, sel_bias)``: per selected cluster the
-    kernel also loads its centroid row and accumulates it with *negative*
-    weight ``-exp(softcap(q.k_syn)*scale + log count - m)``.  Stage 1
+  * **decrement** ``(k_sel, v_sel, sel_bias)``: the first grid step loads
+    the I selected clusters' centroid rows at once and starts the
+    accumulator with their terms at *negative* weight
+    ``-exp(softcap(q.k_syn)*scale + log count - m)``.  Stage 1
     (fused_synopsis) emits partials over ALL centroids (selection isn't
     known yet there); this subtraction removes exactly the selected
     centroids' terms, so ``merge(stage1, stage2)`` equals the masked-bias
@@ -64,20 +65,52 @@ def _kernel(sel_ref, q_ref, k_ref, v_ref, *rest, sm_scale: float,
   o_ref, m_ref, l_ref, acc, m_s, l_s = it
 
   b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+  q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
 
   @pl.when(j == 0)
   def _init():
-    acc[...] = jnp.zeros_like(acc)
-    m_s[...] = jnp.full_like(m_s, NEG_INF)
-    l_s[...] = jnp.zeros_like(l_s)
+    if not has_dec:
+      acc[...] = jnp.zeros_like(acc)
+      m_s[...] = jnp.full_like(m_s, NEG_INF)
+      l_s[...] = jnp.zeros_like(l_s)
+      return
+    # Decrement, once for all I selected clusters: their centroid rows'
+    # stage-1 terms start the accumulator with negative weight.  Invalid
+    # entries carry a NEG_INF bias (zero weight once a real logit lands).
+    kc = kc_ref[0, 0].astype(jnp.float32)           # (I, D) centroid rows
+    vc = vc_ref[0, 0].astype(jnp.float32)
+    s_c = _cap(jax.lax.dot_general(
+        q, kc, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale, cap)
+    s_c = s_c + cb_ref[0, 0].astype(jnp.float32)    # (G, I)
+    m0 = jnp.max(s_c, axis=-1, keepdims=True)
+    p_c = jnp.exp(s_c - m0)
+    acc[...] = -jax.lax.dot_general(
+        p_c, vc, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_s[...] = m0
+    l_s[...] = -jnp.sum(p_c, axis=-1, keepdims=True)
 
-  q = q_ref[0].astype(jnp.float32)                  # (G, D)
+  def _accumulate(logits, v, pv_scale=None):
+    m_prev = m_s[...]                               # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    p = jnp.exp(logits - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = p if pv_scale is None else p * pv_scale
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
+        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_s[...] = m_new
+
+  def _lane(ref, jc):
+    # This step's per-cluster scale out of the (1, I) row: (1, 1).
+    row = ref[0, 0].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == jc, row, 0.0), axis=-1, keepdims=True)
 
   @pl.when(j < num_i)
   def _cluster():
     jc = jnp.minimum(j, num_i - 1)
     valid = sel_ref[b, h, jc] >= 0
-
     k = k_ref[0, 0].astype(jnp.float32)             # (C, D)
     v = v_ref[0, 0].astype(jnp.float32)
     raw = jax.lax.dot_general(
@@ -87,35 +120,10 @@ def _kernel(sel_ref, q_ref, k_ref, v_ref, *rest, sm_scale: float,
       # Per-cluster scalar dequant folded into the logits: this step's
       # whole (C, D) block shares one scale, so it multiplies through
       # AFTER the matmul (never a materialized f32 block).
-      raw = raw * ksc_ref[0, 0, 0].astype(jnp.float32)
+      raw = raw * _lane(ksc_ref, jc)
     logits = _cap(raw * sm_scale, cap)
     logits = jnp.where(valid, logits, NEG_INF)      # mask padded clusters
-
-    m_prev = m_s[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-    if has_dec:
-      kc = kc_ref[0, 0].astype(jnp.float32)         # (1, D) centroid row
-      s_c = _cap(jax.lax.dot_general(
-          q, kc, (((1,), (1,)), ((), ())),
-          preferred_element_type=jnp.float32) * sm_scale, cap)
-      s_c = s_c + cb_ref[0, 0, 0].astype(jnp.float32)   # (G, 1)
-      s_c = jnp.where(valid, s_c, NEG_INF)
-      m_new = jnp.maximum(m_new, jnp.max(s_c, axis=-1))
-
-    p = jnp.exp(logits - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_s[:, 0] * alpha + jnp.sum(p, axis=-1)
-    pv = p if not has_kq else p * vsc_ref[0, 0, 0].astype(jnp.float32)
-    acc_new = acc[...] * alpha[:, None] + jax.lax.dot_general(
-        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    if has_dec:
-      vc = vc_ref[0, 0].astype(jnp.float32)         # (1, D)
-      p_c = jnp.exp(s_c - m_new[:, None])           # (G, 1)
-      l_new = l_new - p_c[:, 0]
-      acc_new = acc_new - p_c * vc                  # negative-weight term
-    acc[...] = acc_new
-    m_s[:, 0] = m_new
-    l_s[:, 0] = l_new
+    _accumulate(logits, v, _lane(vsc_ref, jc) if has_kq else None)
 
   if has_ext:
     @pl.when(j == num_i)
@@ -125,27 +133,17 @@ def _kernel(sel_ref, q_ref, k_ref, v_ref, *rest, sm_scale: float,
       logits = _cap(jax.lax.dot_general(
           q, ke, (((1,), (1,)), ((), ())),
           preferred_element_type=jnp.float32) * sm_scale, cap)
-      logits = logits + eb_ref[0][None, :].astype(jnp.float32)
-
-      m_prev = m_s[:, 0]
-      m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-      p = jnp.exp(logits - m_new[:, None])
-      alpha = jnp.exp(m_prev - m_new)
-      l_s[:, 0] = l_s[:, 0] * alpha + jnp.sum(p, axis=-1)
-      acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
-          p, ve, (((1,), (0,)), ((), ())),
-          preferred_element_type=jnp.float32)
-      m_s[:, 0] = m_new
+      _accumulate(logits + eb_ref[0].astype(jnp.float32), ve)
 
   @pl.when(j == num_steps - 1)
   def _flush():
-    l_fin = l_s[:, 0]
+    l_fin = l_s[...]
     # The decrement can cancel a degenerate (uniform) cluster's mass to
     # ~0; keep o*l == acc finite for the downstream merge.
     safe = jnp.where(jnp.abs(l_fin) > 1e-30, l_fin, 1.0)
-    o_ref[0] = (acc[...] / safe[:, None]).astype(o_ref.dtype)
-    m_ref[0] = m_s[:, 0]
-    l_ref[0] = l_fin
+    o_ref[0, 0] = (acc[...] / safe).astype(o_ref.dtype)
+    m_ref[0, 0] = m_s[...]
+    l_ref[0, 0] = l_fin
 
 
 @functools.partial(
@@ -175,9 +173,9 @@ def block_gather_attention(
   Plain call: exact attention over the selected cluster blocks.  With the
   fused epilogue inputs it additionally subtracts the selected centroids'
   stage-1 terms and folds in the recent/self extras (see module doc).
-  With ``kv_k_scale``/``kv_v_scale`` the sorted KV is quantized and each
-  grid step's scalar-prefetched index also steers a (1,) scale DMA —
-  dequant multiplies into the logits / the p·v weights in-grid
+  With ``kv_k_scale``/``kv_v_scale`` the sorted KV is quantized: the
+  selected clusters' scales are gathered once into a (1, I) row, and
+  grid step j multiplies lane j into the logits / the p·v weights
   (DESIGN.md §15).
   """
   B, H, D = q.shape
@@ -200,52 +198,47 @@ def block_gather_attention(
     jc = jnp.minimum(j, I - 1)
     return (b, h, jnp.maximum(sel[b, h, jc], 0), 0)
 
-  def _sel_row(b, h, j, sel):
-    return (b, h, jnp.minimum(j, I - 1), 0)
-
-  def _scale_index(b, h, j, sel):
-    # Same clamp as _kv_index, one scalar per cluster block.
-    jc = jnp.minimum(j, I - 1)
-    return (b, h, jnp.maximum(sel[b, h, jc], 0))
-
+  # Mosaic tiling: every block spans whole trailing dims or (8, 128)
+  # multiples — q/o as (B, Hkv, G, D), m/l as (B, Hkv, G, 1), per-cluster
+  # rows as (.., 1, I); blocks that do not move with j are fetched once
+  # per (b, h).
+  head = lambda b, h, j, sel: (b, h, 0, 0)
+  safe = jnp.maximum(selected, 0)
   in_specs = [
-      pl.BlockSpec((1, G, D), lambda b, h, j, sel: (b, h, 0)),
+      pl.BlockSpec((1, 1, G, D), head),
       pl.BlockSpec((1, 1, C, D), _kv_index),
       pl.BlockSpec((1, 1, C, D), _kv_index),
   ]
-  args = [q, k, v]
+  args = [q.reshape(B, Hkv, G, D), k, v]
+  row_spec = pl.BlockSpec((1, 1, 1, I), head)
   if has_kq:
-    in_specs += [
-        pl.BlockSpec((1, 1, 1), _scale_index),
-        pl.BlockSpec((1, 1, 1), _scale_index),
-    ]
-    args += [kv_k_scale.astype(jnp.float32), kv_v_scale.astype(jnp.float32)]
+    # The selected clusters' scales, gathered once: step j reads lane j.
+    in_specs += [row_spec, row_spec]
+    args += [jnp.take_along_axis(sc.astype(jnp.float32), safe, axis=2
+                                 ).reshape(B, Hkv, 1, I)
+             for sc in (kv_k_scale, kv_v_scale)]
   if has_dec:
-    in_specs += [
-        pl.BlockSpec((1, 1, 1, D), _sel_row),
-        pl.BlockSpec((1, 1, 1, D), _sel_row),
-        pl.BlockSpec((1, 1, 1), lambda b, h, j, sel:
-                     (b, h, jnp.minimum(j, I - 1))),
-    ]
-    args += [k_sel, v_sel, sel_bias.astype(jnp.float32)]
+    in_specs += [pl.BlockSpec((1, 1, I, D), head),
+                 pl.BlockSpec((1, 1, I, D), head), row_spec]
+    args += [k_sel, v_sel,
+             jnp.where(selected >= 0, sel_bias.astype(jnp.float32),
+                       NEG_INF).reshape(B, Hkv, 1, I)]
   if has_ext:
     E = extras_k.shape[2]
     in_specs += [
-        pl.BlockSpec((1, 1, E, D), lambda b, h, j, sel: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, E, D), lambda b, h, j, sel: (b, h, 0, 0)),
-        pl.BlockSpec((1, E), lambda b, h, j, sel: (b, 0)),
+        pl.BlockSpec((1, 1, E, D), head),
+        pl.BlockSpec((1, 1, E, D), head),
+        pl.BlockSpec((1, 1, E), lambda b, h, j, sel: (b, 0, 0)),
     ]
-    args += [extras_k, extras_v, extras_bias.astype(jnp.float32)]
+    args += [extras_k, extras_v,
+             extras_bias.astype(jnp.float32).reshape(B, 1, E)]
 
+  stat_spec = pl.BlockSpec((1, 1, G, 1), head)
   grid_spec = pltpu.PrefetchScalarGridSpec(
       num_scalar_prefetch=1,
       grid=grid,
       in_specs=in_specs,
-      out_specs=[
-          pl.BlockSpec((1, G, D), lambda b, h, j, sel: (b, h, 0)),
-          pl.BlockSpec((1, G), lambda b, h, j, sel: (b, h)),
-          pl.BlockSpec((1, G), lambda b, h, j, sel: (b, h)),
-      ],
+      out_specs=[pl.BlockSpec((1, 1, G, D), head), stat_spec, stat_spec],
       scratch_shapes=[
           pltpu.VMEM((G, D), jnp.float32),
           pltpu.VMEM((G, 1), jnp.float32),
@@ -258,12 +251,12 @@ def block_gather_attention(
                         has_ext=has_ext, has_kq=has_kq),
       grid_spec=grid_spec,
       out_shape=[
-          jax.ShapeDtypeStruct((B, H, D), jnp.float32),
-          jax.ShapeDtypeStruct((B, H), jnp.float32),
-          jax.ShapeDtypeStruct((B, H), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
       ],
       interpret=interpret,
       name="block_gather_attention",
   )
   out, m, l = fn(selected.astype(jnp.int32), *args)
-  return out, m, l
+  return out.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H)

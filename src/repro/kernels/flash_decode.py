@@ -41,7 +41,7 @@ def _kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, cap,
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
 
-  q = q_ref[0].astype(jnp.float32)                  # (G, D)
+  q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
   k = k_ref[0, 0].astype(jnp.float32)               # (bs, D)
   v = v_ref[0, 0].astype(jnp.float32)               # (bs, D)
 
@@ -50,25 +50,24 @@ def _kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, cap,
       preferred_element_type=jnp.float32) * sm_scale
   logits = apply_softcap(logits, cap)
   if bias_ref is not None:
-    logits = logits + bias_ref[0, 0][None, :].astype(jnp.float32)
+    logits = logits + bias_ref[0, 0].astype(jnp.float32)   # (1, bs) row
 
-  m_prev = m_s[:, 0]                                # (G,)
-  m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-  p = jnp.exp(logits - m_new[:, None])              # (G, bs)
-  alpha = jnp.exp(m_prev - m_new)                   # (G,)
-  l_new = l_s[:, 0] * alpha + jnp.sum(p, axis=-1)
-  acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
+  m_prev = m_s[...]                                 # (G, 1)
+  m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+  p = jnp.exp(logits - m_new)                       # (G, bs)
+  alpha = jnp.exp(m_prev - m_new)                   # (G, 1)
+  l_new = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+  acc[...] = acc[...] * alpha + jax.lax.dot_general(
       p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-  m_s[:, 0] = m_new
-  l_s[:, 0] = l_new
+  m_s[...] = m_new
+  l_s[...] = l_new
 
   @pl.when(s_idx == num_s_blocks - 1)
   def _flush():
-    l_fin = l_s[:, 0]
-    o_ref[0] = (acc[...] / jnp.maximum(l_fin, 1e-30)[:, None]).astype(
-        o_ref.dtype)
-    m_ref[0] = m_s[:, 0]
-    l_ref[0] = l_fin
+    l_fin = l_s[...]
+    o_ref[0, 0] = (acc[...] / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+    m_ref[0, 0] = m_s[...]
+    l_ref[0, 0] = l_fin
 
 
 @functools.partial(
@@ -95,28 +94,30 @@ def flash_decode(
   ns = S // block_s
 
   grid = (B, Hkv, ns)
+  # Mosaic tiling: q/o as (B, Hkv, G, D), m/l as (B, Hkv, G, 1), the bias
+  # as (B, Hkv, 1, S) rows — every block spans whole trailing dims or
+  # (8, 128) multiples; reshaped back after the call.
+  head = lambda b, h, s: (b, h, 0, 0)
   in_specs = [
-      pl.BlockSpec((1, G, D), lambda b, h, s: (b, h, 0)),
+      pl.BlockSpec((1, 1, G, D), head),
       pl.BlockSpec((1, 1, block_s, D), lambda b, h, s: (b, h, s, 0)),
       pl.BlockSpec((1, 1, block_s, D), lambda b, h, s: (b, h, s, 0)),
   ]
-  args = [q.reshape(B, H, D), k, v]
+  args = [q.reshape(B, Hkv, G, D), k, v]
   if bias is not None:
-    in_specs.append(pl.BlockSpec((1, 1, block_s), lambda b, h, s: (b, h, s)))
-    args.append(bias)
+    in_specs.append(pl.BlockSpec((1, 1, 1, block_s),
+                                 lambda b, h, s: (b, h, 0, s)))
+    args.append(bias.reshape(B, Hkv, 1, S))
 
   # Partials stay f32 regardless of input dtype: they feed merge_partials
   # (self-KV, shard compose) and rounding mid-merge would accumulate.
   out_shape = [
-      jax.ShapeDtypeStruct((B, H, D), jnp.float32),
-      jax.ShapeDtypeStruct((B, H), jnp.float32),
-      jax.ShapeDtypeStruct((B, H), jnp.float32),
+      jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+      jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+      jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
   ]
-  out_specs = [
-      pl.BlockSpec((1, G, D), lambda b, h, s: (b, h, 0)),
-      pl.BlockSpec((1, G), lambda b, h, s: (b, h)),
-      pl.BlockSpec((1, G), lambda b, h, s: (b, h)),
-  ]
+  stat_spec = pl.BlockSpec((1, 1, G, 1), head)
+  out_specs = [pl.BlockSpec((1, 1, G, D), head), stat_spec, stat_spec]
   scratch = [
       pltpu.VMEM((G, D), jnp.float32),
       pltpu.VMEM((G, 1), jnp.float32),
@@ -134,4 +135,4 @@ def flash_decode(
       name="flash_decode",
   )
   out, m, l = fn(*args)
-  return out, m, l
+  return out.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H)

@@ -53,7 +53,7 @@ def _kernel(q_ref, k_ref, v_ref, cb_ref, *rest, sm_scale: float,
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
 
-  q = q_ref[0].astype(jnp.float32)                  # (G, D)
+  q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
   k = k_ref[0, 0].astype(jnp.float32)               # (bm, D)
   v = v_ref[0, 0].astype(jnp.float32)               # (bm, D)
 
@@ -64,36 +64,36 @@ def _kernel(q_ref, k_ref, v_ref, cb_ref, *rest, sm_scale: float,
     # Dequantize in the accumulator: the per-centroid k-scale (>= 0, so
     # the score ranking is preserved) multiplies the raw logits; k_syn
     # itself is never materialized in f32.
-    logits = logits * ks_ref[0, 0][None, :].astype(jnp.float32)
+    logits = logits * ks_ref[0, 0].astype(jnp.float32)
   logits = logits * sm_scale
 
   # Use 1: correlation scores (uncapped — softcap is monotone, ranking
   # unchanged; matches ref.synopsis_score_ref).
-  s_ref[0, 0] = jnp.max(logits, axis=0)             # (bm,)
+  s_ref[0, 0] = jnp.max(logits, axis=0, keepdims=True)   # (1, bm)
 
   # Use 2: stage-1 attention partials over the same tile.
   logits = apply_softcap(logits, cap)
-  logits = logits + cb_ref[0][None, :].astype(jnp.float32)
+  logits = logits + cb_ref[0].astype(jnp.float32)   # (1, bm) bias row
 
-  m_prev = m_s[:, 0]
-  m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
-  p = jnp.exp(logits - m_new[:, None])
+  m_prev = m_s[...]                                 # (G, 1)
+  m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+  p = jnp.exp(logits - m_new)
   alpha = jnp.exp(m_prev - m_new)
-  l_new = l_s[:, 0] * alpha + jnp.sum(p, axis=-1)
+  l_new = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
   # v-scale weights p entering the p·v matmul; l stays unscaled (the
   # softmax weights are scale-free — only the value rows are quantized).
-  pv = p if not has_scale else p * vs_ref[0, 0][None, :].astype(jnp.float32)
-  acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
+  pv = p if not has_scale else p * vs_ref[0, 0].astype(jnp.float32)
+  acc[...] = acc[...] * alpha + jax.lax.dot_general(
       pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-  m_s[:, 0] = m_new
-  l_s[:, 0] = l_new
+  m_s[...] = m_new
+  l_s[...] = l_new
 
   @pl.when(m_idx == num_m_blocks - 1)
   def _flush():
-    l_fin = l_s[:, 0]
-    o_ref[0] = acc[...] / jnp.maximum(l_fin, 1e-30)[:, None]
-    m_ref[0] = m_s[:, 0]
-    l_ref[0] = l_fin
+    l_fin = l_s[...]
+    o_ref[0, 0] = acc[...] / jnp.maximum(l_fin, 1e-30)
+    m_ref[0, 0] = m_s[...]
+    l_ref[0, 0] = l_fin
 
 
 @functools.partial(
@@ -122,36 +122,37 @@ def fused_synopsis_score_attention(
     block_m = M
   nm = M // block_m
 
+  # Mosaic tiling: every block spans whole trailing dims or (8, 128)
+  # multiples, so the GQA group and the per-centroid rows get their own
+  # axes — q (B, Hkv, G, D), bias/scale/score rows (.., 1, M), m/l
+  # (B, Hkv, G, 1) — reshaped back after the call.
   in_specs = [
-      pl.BlockSpec((1, G, D), lambda b, h, m: (b, h, 0)),
+      pl.BlockSpec((1, 1, G, D), lambda b, h, m: (b, h, 0, 0)),
       pl.BlockSpec((1, 1, block_m, D), lambda b, h, m: (b, h, m, 0)),
       pl.BlockSpec((1, 1, block_m, D), lambda b, h, m: (b, h, m, 0)),
-      pl.BlockSpec((1, block_m), lambda b, h, m: (b, m)),
+      pl.BlockSpec((1, 1, block_m), lambda b, h, m: (b, 0, m)),
   ]
-  args = [q, k_syn, v_syn, cbias.astype(jnp.float32)]
+  args = [q.reshape(B, Hkv, G, D), k_syn, v_syn,
+          cbias.astype(jnp.float32).reshape(B, 1, M)]
+  row_spec = pl.BlockSpec((1, 1, 1, block_m), lambda b, h, m: (b, h, 0, m))
   if has_scale:
-    in_specs += [
-        pl.BlockSpec((1, 1, block_m), lambda b, h, m: (b, h, m)),
-        pl.BlockSpec((1, 1, block_m), lambda b, h, m: (b, h, m)),
-    ]
-    args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    in_specs += [row_spec, row_spec]
+    args += [k_scale.astype(jnp.float32).reshape(B, Hkv, 1, M),
+             v_scale.astype(jnp.float32).reshape(B, Hkv, 1, M)]
+  group_spec = pl.BlockSpec((1, 1, G, D), lambda b, h, m: (b, h, 0, 0))
+  stat_spec = pl.BlockSpec((1, 1, G, 1), lambda b, h, m: (b, h, 0, 0))
 
   fn = pl.pallas_call(
       functools.partial(_kernel, sm_scale=sm_scale, cap=cap,
                         num_m_blocks=nm, has_scale=has_scale),
       grid=(B, Hkv, nm),
       in_specs=in_specs,
-      out_specs=[
-          pl.BlockSpec((1, 1, block_m), lambda b, h, m: (b, h, m)),
-          pl.BlockSpec((1, G, D), lambda b, h, m: (b, h, 0)),
-          pl.BlockSpec((1, G), lambda b, h, m: (b, h)),
-          pl.BlockSpec((1, G), lambda b, h, m: (b, h)),
-      ],
+      out_specs=[row_spec, group_spec, stat_spec, stat_spec],
       out_shape=[
-          jax.ShapeDtypeStruct((B, Hkv, M), jnp.float32),
-          jax.ShapeDtypeStruct((B, H, D), jnp.float32),
-          jax.ShapeDtypeStruct((B, H), jnp.float32),
-          jax.ShapeDtypeStruct((B, H), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, 1, M), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+          jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
       ],
       scratch_shapes=[
           pltpu.VMEM((G, D), jnp.float32),
@@ -162,4 +163,5 @@ def fused_synopsis_score_attention(
       name="fused_synopsis_score_attention",
   )
   scores, o, m, l = fn(*args)
-  return scores, (o, m, l)
+  return scores.reshape(B, Hkv, M), (o.reshape(B, H, D), m.reshape(B, H),
+                                     l.reshape(B, H))

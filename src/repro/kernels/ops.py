@@ -144,8 +144,9 @@ def synopsis_build(
   dict including the quantized tables + per-block scales, emitted in the
   same streaming pass.
 
-  The Pallas path streams each row through VMEM exactly once
-  (scalar-prefetch-steered row DMA); the XLA path keeps the
+  The Pallas path streams each row through VMEM exactly once (one
+  cluster per grid step, its rows DMA'd by the permutation); the XLA
+  path keeps the
   take_along_axis -> reshape-mean chain (two passes + gather copies)."""
   qc = qt.parse_qconfig(qconfig)
   if impl == "xla":
@@ -217,7 +218,7 @@ def refine_stage2(q, k, v, selected, k_syn, v_syn, counts, *,
   Quantized arenas (DESIGN.md §15): ``syn_scales`` dequantizes the I
   gathered centroid decrement rows here (tiny — outside the kernel);
   ``kv_scales`` = (k_scale, v_scale) (B, Hkv, M) rides into the kernel,
-  whose scalar-prefetched cluster index steers the per-block scale."""
+  which reads the selected clusters' per-block scales."""
   B, H, _ = q.shape
   Hkv = k.shape[1]
   sel = selected

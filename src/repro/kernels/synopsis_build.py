@@ -8,17 +8,19 @@ C-token cluster into its mean-centroid row — in ONE streaming pass.
 The unfused XLA chain (``ref.synopsis_build_ref``) materialises the
 sorted cache with ``take_along_axis`` (HBM write), then re-reads it for
 the reshape-mean (HBM read) — two full passes over the cache plus the
-gather's scatter traffic.  Here the permutation is **scalar-prefetched**
-(SMEM) so the BlockSpec ``index_map`` steers each grid step's HBM->VMEM
-DMA straight to source row ``perm[n, m*C + c]``; the step emits the
-permuted row to its destination slot and folds it into the f32 centroid
-accumulator, flushing ``k_syn``/``v_syn``/``counts`` at the last member
-of each cluster.  Every cache row moves through VMEM exactly once.
+gather's scatter traffic.  Here the cache stays in HBM and each grid step
+gathers one whole cluster: the step's C permutation entries arrive in
+SMEM, and C row DMAs (one per member token, all KV heads at once) pull
+the rows straight into a VMEM cluster buffer.  The step writes the buffer
+out as the cluster's sorted block and reduces it to the centroid rows.
+Every cache row moves through VMEM exactly once.
 
-Grid (N, Hkv, M, C) — one row per step; Pallas double-buffers the row
-DMAs across steps so the gather pipeline stays latency-hidden.  ``counts``
-is emitted per (N, Hkv, M) (the wrapper returns the h=0 slice — clusters
-are shared across KV heads by construction).
+Grid (N, M) — one cluster per step.  Only the current cluster's
+permutation slice is ever resident in SMEM (the whole (N, S) table does
+not fit its 1 MiB at S=32k over 8 layers).  Centroids come out in
+(N, M, Hkv, D) layout so every output block spans whole trailing dims
+(the Mosaic (8, 128) rule); the wrapper moves the head axis back.
+``counts`` is C for every cluster by construction.
 
 ``absorb_recent`` reuses the same kernel with the identity permutation:
 the recent ring buffer's R tokens become R/C new clusters appended to the
@@ -38,60 +40,67 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels import quant as qt
 
 
-def _kernel(perm_ref, k_ref, v_ref, *rest, cluster_size: int,
+def _kernel(perm_ref, k_hbm, v_hbm, *rest, cluster_size: int,
             quant: Optional[str], quant_kv: bool):
   it = iter(rest)
-  ks_ref, vs_ref, ksyn_ref, vsyn_ref, cnt_ref = (
-      next(it), next(it), next(it), next(it), next(it))
+  ks_ref, vs_ref, ksyn_ref, vsyn_ref = next(it), next(it), next(it), next(it)
   kss_ref = vss_ref = kvs_ref = vvs_ref = None
   if quant:                     # per-centroid synopsis scales (§15)
     kss_ref, vss_ref = next(it), next(it)
     if quant_kv:                # per-cluster-block sorted-KV scales
       kvs_ref, vvs_ref = next(it), next(it)
-  kacc, vacc = next(it), next(it)
-  kblk = vblk = None
-  if quant_kv:                  # buffer the cluster block for one-shot
-    kblk, vblk = next(it), next(it)   # amax + encode at the flush
+  kbuf, vbuf, sem = next(it), next(it), next(it)
+  n = pl.program_id(0)
 
-  c = pl.program_id(3)
+  def _copies(c, src):
+    return (pltpu.make_async_copy(k_hbm.at[n, pl.ds(src, 1)],
+                                  kbuf.at[pl.ds(c, 1)], sem.at[0]),
+            pltpu.make_async_copy(v_hbm.at[n, pl.ds(src, 1)],
+                                  vbuf.at[pl.ds(c, 1)], sem.at[1]))
 
-  @pl.when(c == 0)
-  def _init():
-    kacc[...] = jnp.zeros_like(kacc)
-    vacc[...] = jnp.zeros_like(vacc)
+  def _start(c, carry):
+    for cp in _copies(c, perm_ref[0, 0, 0, c]):
+      cp.start()
+    return carry
 
-  krow = k_ref[0, 0].astype(jnp.float32)              # (1, D)
-  vrow = v_ref[0, 0].astype(jnp.float32)
-  if quant_kv:
-    kblk[pl.ds(c, 1), :] = krow
-    vblk[pl.ds(c, 1), :] = vrow
+  def _wait(c, carry):
+    for cp in _copies(c, 0):    # same byte count as every started copy
+      cp.wait()
+    return carry
+
+  jax.lax.fori_loop(0, cluster_size, _start, 0)
+  jax.lax.fori_loop(0, cluster_size, _wait, 0)
+
+  kb = jnp.swapaxes(kbuf[...].astype(jnp.float32), 0, 1)   # (Hkv, C, D)
+  vb = jnp.swapaxes(vbuf[...].astype(jnp.float32), 0, 1)
+  inv = jnp.float32(1.0 / cluster_size)
+  kmean = jnp.sum(kb, axis=1) * inv                   # (Hkv, D)
+  vmean = jnp.sum(vb, axis=1) * inv
+
+  def _q(x, s_ref, axes):
+    # Quantize from f32: scale = amax/qmax over ``axes`` (one scale per
+    # head's centroid row or cluster block), the same deterministic
+    # round the XLA reference uses.
+    amax = jnp.abs(x)
+    for ax in reversed(axes):   # one axis at a time (Mosaic reductions)
+      amax = jnp.max(amax, axis=ax, keepdims=True)
+    scale = amax / qt.qmax(quant)
+    s_ref[0, 0] = scale.reshape(scale.shape[0], 1)
+    inv_s = jnp.where(scale > 0, 1.0 / jnp.maximum(scale, 1e-30), 0.0)
+    return qt.encode_scaled(x * inv_s, quant)
+
+  if quant:
+    ksyn_ref[0, 0] = _q(kmean, kss_ref, (1,))
+    vsyn_ref[0, 0] = _q(vmean, vss_ref, (1,))
   else:
-    ks_ref[0, 0] = krow.astype(ks_ref.dtype)          # permuted cache row
-    vs_ref[0, 0] = vrow.astype(vs_ref.dtype)
-  kacc[...] += krow
-  vacc[...] += vrow
-
-  def _q(x, s_ref, o_ref):
-    # Quantize from the f32 accumulator/block: scale = amax/qmax, the
-    # encode is the same deterministic round the XLA reference uses.
-    scale = jnp.max(jnp.abs(x)) / qt.qmax(quant)
-    s_ref[0, 0, 0] = scale
-    inv = jnp.where(scale > 0, 1.0 / jnp.maximum(scale, 1e-30), 0.0)
-    o_ref[0, 0] = qt.encode_scaled(x * inv, quant)
-
-  @pl.when(c == cluster_size - 1)
-  def _flush():
-    inv = jnp.float32(1.0 / cluster_size)
-    if quant:
-      _q(kacc[...] * inv, kss_ref, ksyn_ref)
-      _q(vacc[...] * inv, vss_ref, vsyn_ref)
-    else:
-      ksyn_ref[0, 0] = (kacc[...] * inv).astype(ksyn_ref.dtype)
-      vsyn_ref[0, 0] = (vacc[...] * inv).astype(vsyn_ref.dtype)
-    if quant_kv:
-      _q(kblk[...], kvs_ref, ks_ref)
-      _q(vblk[...], vvs_ref, vs_ref)
-    cnt_ref[0, 0, 0] = jnp.float32(cluster_size)
+    ksyn_ref[0, 0] = kmean.astype(ksyn_ref.dtype)
+    vsyn_ref[0, 0] = vmean.astype(vsyn_ref.dtype)
+  if quant_kv:
+    ks_ref[0] = _q(kb, kvs_ref, (1, 2))
+    vs_ref[0] = _q(vb, vvs_ref, (1, 2))
+  else:
+    ks_ref[0] = kb.astype(ks_ref.dtype)               # permuted cluster
+    vs_ref[0] = vb.astype(vs_ref.dtype)
 
 
 @functools.partial(
@@ -111,10 +120,10 @@ def segment_build(
 
   With ``quant`` the same streaming pass also emits the quantized arenas
   + scales (DESIGN.md §15) and returns the arena dict instead: centroids
-  are quantized from the f32 accumulator at each cluster's flush (one
-  scale per centroid row); with the ``+kv`` specs the sorted cache block
-  is buffered in VMEM and quantized whole at the flush (one scale per
-  cluster block), so no f32 sorted copy ever lands in HBM."""
+  are quantized from the f32 cluster mean (one scale per centroid row);
+  with the ``+kv`` specs the gathered cluster block is quantized whole
+  (one scale per cluster block), so no f32 sorted copy ever lands in
+  HBM."""
   N, Hkv, S, D = k.shape
   C = cluster_size
   assert S % C == 0, (S, C)
@@ -122,70 +131,52 @@ def segment_build(
   qc = qt.parse_qconfig(quant)
   qdt = qt.qdtype(qc.kind) if qc.enabled else None
 
-  def _src(n, h, m, c, perm):
-    return (n, h, perm[n, m * C + c], 0)
-
-  _syn = lambda n, h, m, c, perm: (n, h, m, 0)
-  _scl = lambda n, h, m, c, perm: (n, h, m)
-  if qc.sorted_kv:
-    # Whole-cluster block output, written once at the flush.
-    sorted_spec = pl.BlockSpec((1, 1, C, D), _syn)
-  else:
-    sorted_spec = pl.BlockSpec(
-        (1, 1, 1, D), lambda n, h, m, c, perm: (n, h, m * C + c, 0))
-
-  out_specs = [
-      sorted_spec,
-      sorted_spec,
-      pl.BlockSpec((1, 1, 1, D), _syn),
-      pl.BlockSpec((1, 1, 1, D), _syn),
-      pl.BlockSpec((1, 1, 1), _scl),
-  ]
+  sorted_spec = pl.BlockSpec((1, Hkv, C, D), lambda n, m: (n, 0, m, 0))
+  syn_spec = pl.BlockSpec((1, 1, Hkv, D), lambda n, m: (n, m, 0, 0))
+  scale_spec = pl.BlockSpec((1, 1, Hkv, 1), lambda n, m: (n, m, 0, 0))
+  out_specs = [sorted_spec, sorted_spec, syn_spec, syn_spec]
   out_shape = [
       jax.ShapeDtypeStruct((N, Hkv, S, D), qdt if qc.sorted_kv else k.dtype),
       jax.ShapeDtypeStruct((N, Hkv, S, D), qdt if qc.sorted_kv else v.dtype),
-      jax.ShapeDtypeStruct((N, Hkv, M, D), qdt if qc.enabled else k.dtype),
-      jax.ShapeDtypeStruct((N, Hkv, M, D), qdt if qc.enabled else v.dtype),
-      jax.ShapeDtypeStruct((N, Hkv, M), jnp.float32),
+      jax.ShapeDtypeStruct((N, M, Hkv, D), qdt if qc.enabled else k.dtype),
+      jax.ShapeDtypeStruct((N, M, Hkv, D), qdt if qc.enabled else v.dtype),
   ]
-  scratch = [
-      pltpu.VMEM((1, D), jnp.float32),
-      pltpu.VMEM((1, D), jnp.float32),
-  ]
-  if qc.enabled:
-    out_specs += [pl.BlockSpec((1, 1, 1), _scl)] * 2
-    out_shape += [jax.ShapeDtypeStruct((N, Hkv, M), jnp.float32)] * 2
-    if qc.sorted_kv:
-      out_specs += [pl.BlockSpec((1, 1, 1), _scl)] * 2
-      out_shape += [jax.ShapeDtypeStruct((N, Hkv, M), jnp.float32)] * 2
-      scratch += [pltpu.VMEM((C, D), jnp.float32)] * 2
+  n_scales = (2 + 2 * qc.sorted_kv) if qc.enabled else 0
+  out_specs += [scale_spec] * n_scales
+  out_shape += [jax.ShapeDtypeStruct((N, M, Hkv, 1), jnp.float32)] * n_scales
 
-  grid_spec = pltpu.PrefetchScalarGridSpec(
-      num_scalar_prefetch=1,
-      grid=(N, Hkv, M, C),
-      in_specs=[
-          pl.BlockSpec((1, 1, 1, D), _src),
-          pl.BlockSpec((1, 1, 1, D), _src),
-      ],
-      out_specs=out_specs,
-      scratch_shapes=scratch,
-  )
   fn = pl.pallas_call(
       functools.partial(_kernel, cluster_size=C,
                         quant=qc.kind if qc.enabled else None,
                         quant_kv=qc.sorted_kv),
-      grid_spec=grid_spec,
+      grid=(N, M),
+      in_specs=[
+          pl.BlockSpec((1, 1, 1, C), lambda n, m: (n, m, 0, 0),
+                       memory_space=pltpu.SMEM),
+          pl.BlockSpec(memory_space=pl.ANY),
+          pl.BlockSpec(memory_space=pl.ANY),
+      ],
+      out_specs=out_specs,
       out_shape=out_shape,
+      scratch_shapes=[
+          pltpu.VMEM((C, Hkv, D), k.dtype),
+          pltpu.VMEM((C, Hkv, D), v.dtype),
+          pltpu.SemaphoreType.DMA((2,)),
+      ],
       interpret=interpret,
       name="segment_build",
   )
-  outs = fn(perm.astype(jnp.int32), k, v)
-  ks, vs, ksyn, vsyn, cnt = outs[:5]
+  outs = fn(perm.astype(jnp.int32).reshape(N, M, 1, C),
+            jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
+  heads_first = lambda x: jnp.swapaxes(x, 1, 2)       # (N, M, Hkv, ..) ->
+  ks, vs = outs[0], outs[1]                           # (N, Hkv, M, ..)
+  ksyn, vsyn = heads_first(outs[2]), heads_first(outs[3])
+  counts = jnp.full((N, M), float(C), jnp.float32)
   if not qc.enabled:
-    return ks, vs, ksyn, vsyn, cnt[:, 0]
-  res = {"k": ks, "v": vs, "k_syn": ksyn, "v_syn": vsyn,
-         "counts": cnt[:, 0],
-         "k_syn_scale": outs[5], "v_syn_scale": outs[6]}
+    return ks, vs, ksyn, vsyn, counts
+  scales = [heads_first(x)[..., 0] for x in outs[4:]]
+  res = {"k": ks, "v": vs, "k_syn": ksyn, "v_syn": vsyn, "counts": counts,
+         "k_syn_scale": scales[0], "v_syn_scale": scales[1]}
   if qc.sorted_kv:
-    res["k_scale"], res["v_scale"] = outs[7], outs[8]
+    res["k_scale"], res["v_scale"] = scales[2], scales[3]
   return res

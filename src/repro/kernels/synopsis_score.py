@@ -18,12 +18,12 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(q_ref, k_ref, out_ref, *, sm_scale: float):
-  q = q_ref[0].astype(jnp.float32)                  # (G, D)
+  q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
   k = k_ref[0, 0].astype(jnp.float32)               # (bm, D)
   logits = jax.lax.dot_general(
       q, k, (((1,), (1,)), ((), ())),
       preferred_element_type=jnp.float32) * sm_scale
-  out_ref[0, 0] = jnp.max(logits, axis=0)           # (bm,)
+  out_ref[0, 0] = jnp.max(logits, axis=0, keepdims=True)   # (1, bm)
 
 
 @functools.partial(
@@ -46,13 +46,17 @@ def synopsis_score(
   fn = pl.pallas_call(
       functools.partial(_kernel, sm_scale=sm_scale),
       grid=(B, Hkv, M // block_m),
+      # Mosaic tiling: q as (B, Hkv, G, D) and scores as (B, Hkv, 1, M)
+      # rows, so every block spans whole trailing dims or (8, 128)
+      # multiples.
       in_specs=[
-          pl.BlockSpec((1, G, D), lambda b, h, m: (b, h, 0)),
+          pl.BlockSpec((1, 1, G, D), lambda b, h, m: (b, h, 0, 0)),
           pl.BlockSpec((1, 1, block_m, D), lambda b, h, m: (b, h, m, 0)),
       ],
-      out_specs=pl.BlockSpec((1, 1, block_m), lambda b, h, m: (b, h, m)),
-      out_shape=jax.ShapeDtypeStruct((B, Hkv, M), jnp.float32),
+      out_specs=pl.BlockSpec((1, 1, 1, block_m),
+                             lambda b, h, m: (b, h, 0, m)),
+      out_shape=jax.ShapeDtypeStruct((B, Hkv, 1, M), jnp.float32),
       interpret=interpret,
       name="synopsis_score",
   )
-  return fn(q, k_syn)
+  return fn(q.reshape(B, Hkv, G, D), k_syn).reshape(B, Hkv, M)

@@ -56,6 +56,7 @@ def _engine_main(args):
 
   from repro.configs.registry import get_config
   from repro.control import AdmissionConfig, parse_slo_classes
+  from repro.dist.topology import MeshUnavailable
   from repro.serve.engine import EngineConfig, ServingEngine, run_open_loop
   from repro.serve.resilience import parse_fault_spec
   from repro.serving.workload import CF_RATES, hour_rate
@@ -91,12 +92,16 @@ def _engine_main(args):
   if args.cache_capacity > 0 and not args.no_cache:
     from repro.serve.corpus_cache import CacheConfig
     cache = CacheConfig(capacity=args.cache_capacity, delta_unit=C)
-  eng = ServingEngine(cfg, EngineConfig(
-      n_slots=args.n_slots, prompt_len=prompt_len, max_new_tokens=max_new,
-      deadline_ms=args.deadline_ms, policy=args.policy, impl=args.impl,
-      predictor=args.predictor or "affine", admission=admission,
-      cache=cache, contract=args.contract, epsilon=args.epsilon),
-      backend=backend)
+  try:
+    eng = ServingEngine(cfg, EngineConfig(
+        n_slots=args.n_slots, prompt_len=prompt_len,
+        max_new_tokens=max_new, deadline_ms=args.deadline_ms,
+        policy=args.policy, impl=args.impl,
+        predictor=args.predictor or "affine", admission=admission,
+        cache=cache, contract=args.contract, epsilon=args.epsilon),
+        backend=backend)
+  except MeshUnavailable as e:
+    raise SystemExit(f"error: {e}") from None
   print(f"[engine] impl={eng.impl!r} policy={args.policy} "
         f"slots={args.n_slots} prompt={prompt_len} tokens={max_new} "
         f"M={eng.M} buckets={eng.buckets} deadline={args.deadline_ms}ms"
@@ -213,10 +218,13 @@ def _autoscale_main(args, backend):
           "component_hours_static": cost_static}
 
 
-def main():
+def make_parser() -> argparse.ArgumentParser:
   ap = argparse.ArgumentParser()
   ap.add_argument("--arch", default="llama3-8b")
-  ap.add_argument("--smoke", action="store_true", default=True)
+  ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                  default=True,
+                  help="the arch's reduced smoke config (default); "
+                       "--no-smoke selects its published config")
   ap.add_argument("--batch", type=int, default=2)
   ap.add_argument("--prompt-len", type=int, default=256)
   ap.add_argument("--tokens", type=int, default=32)
@@ -251,9 +259,10 @@ def main():
   ap.add_argument("--cluster", type=int, default=0, metavar="N",
                   help="run decode steps on the N-component scatter-"
                        "gather tier (DESIGN.md §9; implies --engine): "
-                       "shard_map over a component mesh when N host "
-                       "devices exist (forced automatically on CPU), "
-                       "stacked execution of the same math otherwise")
+                       "shard_map over a component mesh of N devices "
+                       "(host devices are forced on CPU); on an "
+                       "accelerator with fewer than N chips it exits "
+                       "with an error")
   ap.add_argument("--fleet", action="store_true",
                   help="run the materialized-replica fleet tier "
                        "(DESIGN.md §14; implies --engine, needs "
@@ -363,6 +372,11 @@ def main():
                        "copies; none = bit-identical control arm")
   ap.add_argument("--json", default=None, metavar="PATH",
                   help="write the --engine sweep results as JSON")
+  return ap
+
+
+def main():
+  ap = make_parser()
   args = ap.parse_args()
 
   if args.fleet and not args.cluster:
@@ -372,13 +386,16 @@ def main():
     # The mesh wants one device per component — times the replica rows
     # under --fleet (the 2-D grid) — so on a CPU host force placeholder
     # devices BEFORE jax initialises (same mechanism as launch/dryrun.py).
+    # The flag only sizes the CPU platform: an accelerator host serves
+    # the mesh from its chips or refuses (topology.MeshUnavailable).
     # No-op if the user already set the flag.
     from repro.dist.topology import force_host_devices
     force_host_devices(args.cluster * (max(1, args.replicas)
                                        if args.fleet else 1))
-    return _engine_main(args)
 
-  if args.engine:
+  from repro.launch.compile_cache import enable_compile_cache
+  enable_compile_cache()
+  if args.engine or args.cluster:
     return _engine_main(args)
 
   import jax
@@ -387,7 +404,6 @@ def main():
   from repro.configs.registry import get_config
   from repro.control import BudgetController, make_predictor
   from repro.kernels.ops import resolve_impl
-  from repro.models import common as cm
   from repro.models import transformer as tf
   from repro.serve import synopsis_kv as skv
   from repro.serve.kv_cache import n_attn_positions
@@ -397,8 +413,7 @@ def main():
   cfg = get_config(args.arch, smoke=args.smoke)
   cfg = _apply_quant(cfg, args.quant)
   key = jax.random.PRNGKey(0)
-  params, _ = cm.split(tf.init_model(key, cfg))
-  params = jax.tree.map(lambda p: p.astype(cfg.dtype), params)
+  params = tf.init_params(key, cfg)
 
   impl = resolve_impl(args.impl if args.impl else cfg.synopsis.impl)
   print(f"[impl] prefill/build/decode kernels via {impl!r}")

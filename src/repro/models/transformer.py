@@ -75,13 +75,29 @@ def _init_layer(key, cfg: cm.ModelConfig, spec: cm.LayerSpec) -> dict:
 
 
 def _stack_layers(key, cfg, spec, n: int):
-  """Stack n copies of one pattern position; prepend the 'layers' axis."""
-  keys = jax.random.split(key, n)
-  trees = [_init_layer(k, cfg, spec) for k in keys]
-  def stack(*boxes):
-    return cm.Box(jnp.stack([b.value for b in boxes]),
-                  ("layers",) + boxes[0].axes)
-  return jax.tree.map(stack, *trees, is_leaf=cm.is_box)
+  """Stack n copies of one pattern position; prepend the 'layers' axis.
+  ``vmap`` over the per-layer keys draws the stacked arrays directly (the
+  same values a per-layer loop draws), so no per-layer copy is ever
+  concatenated."""
+  axes = {}
+
+  def one(k):
+    params, axes["tree"] = cm.split(_init_layer(k, cfg, spec))
+    return params
+
+  stacked = jax.vmap(one)(jax.random.split(key, n))
+  return jax.tree.map(lambda v, ax: cm.Box(v, ("layers",) + ax), stacked,
+                      axes["tree"])
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def init_params(key, cfg: cm.ModelConfig):
+  """Parameters in ``cfg.dtype`` from one jitted program (no axes tree).
+  The f32 draws fuse with the cast, so only the ``cfg.dtype`` tree is
+  ever resident on the device — at llama3-8b widths the f32 tree alone
+  would fill most of a 16 GB chip."""
+  params, _ = cm.split(init_model(key, cfg))
+  return jax.tree.map(lambda p: p.astype(cfg.dtype), params)
 
 
 def init_model(key, cfg: cm.ModelConfig):

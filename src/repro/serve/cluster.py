@@ -102,7 +102,8 @@ class ClusterConfig:
   interference: float = 0.25   # lognormal sigma (co-located jobs, per step)
   straggler_prob: float = 0.02
   straggler_scale: float = 8.0
-  use_mesh: Optional[bool] = None   # None -> auto (mesh iff devices >= N)
+  use_mesh: Optional[bool] = None   # None -> mesh; stacked only on a
+                                    # CPU host short of devices
   seed: int = 0
   # -- resilience (DESIGN.md §11; all off by default: faults=None and
   # retries=1 take the exact legacy plan/account path, bit-identical) ----
@@ -435,9 +436,9 @@ def _cluster_sharded(q, csl, topo, alloc, mesh, *, i_max, cluster_size,
       return outs
 
   n_out = 4 if telemetry else 3
-  res = shd.shard_map(
+  res = jax.shard_map(
       body, mesh=mesh, in_specs=(q_spec, specs, self_spec),
-      out_specs=(P(),) * n_out, axis_names=("component",),
+      out_specs=(P(),) * n_out, axis_names=frozenset({"component"}),
       check_vma=False)(q, csl, self_kv)
   aux = {"fe_cover": res[1], "fe_mass": res[2]}
   if telemetry:
@@ -505,14 +506,7 @@ class ClusterStepBackend:
       raise ValueError(f"route {cc.route!r} not in ('fixed', 'rotate')")
     self.topo = ComponentTopology.plan(self.M, cc.n_components,
                                        skew=cc.skew, replicas=cc.replicas)
-    use_mesh = cc.use_mesh
-    self.mesh = make_component_mesh(cc.n_components) \
-        if use_mesh or use_mesh is None else None
-    if use_mesh and self.mesh is None:
-      raise RuntimeError(
-          f"use_mesh=True but < {cc.n_components} devices; run under "
-          f"XLA_FLAGS=--xla_force_host_platform_device_count="
-          f"{cc.n_components}")
+    self.mesh = make_component_mesh(cc.n_components, cc.use_mesh)
     # Resilience (DESIGN.md §11): the fault world, the bounded-retry
     # policy over the replica ring, and mode-aware allocation caps.  The
     # default config (faults=None, recovery=True, retries=1) keeps
@@ -897,7 +891,12 @@ class ClusterMeasuredExport:
   def __init__(self, backend: ClusterStepBackend, full_items: int = 100):
     self.share = backend.comp_share.copy()
     self.massf = backend.mass_ewma / max(backend.mass_ewma.sum(), 1e-30)
-    self.walls = backend.predictor.table() or {0: 5.0}
+    # Running max over the buckets: a wall measured at a larger budget
+    # reads at least as many rows, so a noisy sample below a smaller
+    # bucket's is clock noise, not a faster step.
+    walls = backend.predictor.table() or {0: 5.0}
+    self.walls = dict(zip(sorted(walls), np.maximum.accumulate(
+        [walls[b] for b in sorted(walls)])))
     self.M = backend.M
     self.cluster_size = backend.cfg.synopsis.cluster_size
     self.full_items = full_items

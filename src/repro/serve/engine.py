@@ -272,8 +272,7 @@ class ServingEngine:
         if backend is not None else 1
 
     if params is None:
-      params, _ = cm.split(tf.init_model(jax.random.PRNGKey(ecfg.seed), cfg))
-      params = jax.tree.map(lambda p: p.astype(cfg.dtype), params)
+      params = tf.init_params(jax.random.PRNGKey(ecfg.seed), cfg)
     self.params = params
 
     self._prefill = jax.jit(make_prefill_step(cfg, impl=self.impl))

@@ -269,9 +269,10 @@ def _fleet_sharded(q, csl, topo, alloc, mesh, *, i_max, cluster_size,
       return outs
 
   n_out = 4 if telemetry else 3
-  res = shd.shard_map(
+  res = jax.shard_map(
       body, mesh=mesh, in_specs=(q_spec, specs, self_spec),
-      out_specs=(P(),) * n_out, axis_names=("replica", "component"),
+      out_specs=(P(),) * n_out,
+      axis_names=frozenset({"replica", "component"}),
       check_vma=False)(q, csl, self_kv)
   aux = {"fe_cover": res[1], "fe_mass": res[2]}
   if telemetry:
@@ -307,18 +308,9 @@ class FleetStepBackend(ClusterStepBackend):
           "retries=1, recovery=True): fault injection and the retry "
           "ladder ride the 1-D cluster tier")
     # Re-plan through the fleet entry point (validates R as a grid dim)
-    # and upgrade the mesh to 2-D.  R*N devices make replication real;
-    # with fewer the stacked fallback executes the same math.
+    # and upgrade the mesh to 2-D: R*N devices make replication real.
     self.topo = plan_2d(self.M, cc.n_components, cc.replicas, skew=cc.skew)
-    use_mesh = cc.use_mesh
-    self.mesh = make_fleet_mesh(cc.n_components, cc.replicas) \
-        if use_mesh or use_mesh is None else None
-    if use_mesh and self.mesh is None:
-      raise RuntimeError(
-          f"use_mesh=True but < {cc.replicas * cc.n_components} devices "
-          f"for the (replica={cc.replicas}, component={cc.n_components}) "
-          f"mesh; run under XLA_FLAGS=--xla_force_host_platform_device_"
-          f"count={cc.replicas * cc.n_components}")
+    self.mesh = make_fleet_mesh(cc.n_components, cc.replicas, cc.use_mesh)
     self.attention = make_fleet_attention(self.topo, alloc=cc.alloc,
                                           mesh=self.mesh,
                                           recirculate=cc.recirculate,

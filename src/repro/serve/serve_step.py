@@ -140,15 +140,7 @@ def sharded_synopsis_attention(
     dp, dp_n = (), 1
   bspec = dp if dp else None
 
-  manual = set(axes) | set(dp)
-  if (set(mesh.axis_names) - manual) and not shd.supports_partial_manual():
-    # Partial-manual shard_map (manual over a subset of mesh axes) hits
-    # an XLA partitioner CHECK on legacy jax builds; fall back to the
-    # replicated body rather than crash (same result, GSPMD collectives).
-    return synopsis_decode_attention(
-        q, cache, i_max=i_max, cluster_size=cluster_size,
-        sm_scale=sm_scale, cap=cap, self_kv=self_kv, impl=impl)
-
+  manual = frozenset(axes) | frozenset(dp)
   kv_spec = P(bspec, None, axes, None)
   specs = {"k": kv_spec, "v": kv_spec, "k_syn": kv_spec, "v_syn": kv_spec,
            "counts": P(bspec, axes)}
@@ -223,7 +215,7 @@ def sharded_synopsis_attention(
         acc = ops.merge_partials(acc, (og[i], mg[i], lg[i]))
       return acc[0]
 
-  return shd.shard_map(
+  return jax.shard_map(
       body, mesh=mesh, in_specs=(q_spec, specs, self_spec),
       out_specs=q_spec if dp else P(),
       axis_names=manual, check_vma=False,

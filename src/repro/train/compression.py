@@ -51,22 +51,5 @@ def compressed_pod_psum(grads, err, axis_name: str = "pod"):
           jax.tree.map(lambda t: t[1], pairs, is_leaf=is2))
 
 
-def local_quantise_feedback(grads, err):
-  """Quantise-dequantise + error feedback WITHOUT the manual collective —
-  the numerical behaviour of :func:`compressed_pod_psum` when the runtime
-  cannot lower partial-manual shard_map (GSPMD then carries the already
-  -reduced gradients; the wire stays f32 but optimizer numerics match)."""
-  def one(g, e):
-    g32 = g.astype(jnp.float32) + e
-    q, scale = _quantise(g32)
-    deq = q.astype(jnp.float32) * scale
-    return deq, g32 - deq
-
-  pairs = jax.tree.map(one, grads, err)
-  is2 = lambda x: isinstance(x, tuple) and len(x) == 2
-  return (jax.tree.map(lambda t: t[0], pairs, is_leaf=is2),
-          jax.tree.map(lambda t: t[1], pairs, is_leaf=is2))
-
-
 def init_error_feedback(params) -> Any:
   return jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)

@@ -88,24 +88,19 @@ def make_train_step(cfg: cm.ModelConfig, opt_cfg: opt_lib.OptConfig, *,
     loss, metrics, grads = grads_of(state["params"], batch)
 
     if compress_pods and mesh is not None and "pod" in mesh.shape:
-      if shd.supports_partial_manual():
-        # Cross-pod reduction by hand (int8 + error feedback); within-pod
-        # reductions stay in GSPMD.  shard_map manual only on 'pod'.
-        def red(g, e):
-          return comp.compressed_pod_psum(g, e, "pod")
+      # Cross-pod reduction by hand (int8 + error feedback); within-pod
+      # reductions stay in GSPMD.  shard_map manual only on 'pod'.
+      def red(g, e):
+        return comp.compressed_pod_psum(g, e, "pod")
 
-        from jax.sharding import PartitionSpec as P  # noqa: PLC0415
-        spec = jax.tree.map(lambda _: P(), grads)
-        grads, new_err = shd.shard_map(
-            red, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
-            check_vma=False, axis_names={"pod"},
-        )(grads, state["err"])
-        state = {**state, "err": new_err}
-        grads = jax.tree.map(lambda g: g / mesh.shape["pod"], grads)
-      else:
-        # Legacy runtime: same quantisation numerics, GSPMD reduction.
-        grads, new_err = comp.local_quantise_feedback(grads, state["err"])
-        state = {**state, "err": new_err}
+      from jax.sharding import PartitionSpec as P  # noqa: PLC0415
+      spec = jax.tree.map(lambda _: P(), grads)
+      grads, new_err = jax.shard_map(
+          red, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+          check_vma=False, axis_names=frozenset({"pod"}),
+      )(grads, state["err"])
+      state = {**state, "err": new_err}
+      grads = jax.tree.map(lambda g: g / mesh.shape["pod"], grads)
 
     new_params, new_opt, om = opt_lib.adamw_update(
         grads, state["opt"], state["params"], opt_cfg)

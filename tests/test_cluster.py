@@ -10,7 +10,9 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
-from repro.dist.topology import ComponentTopology, zipf_weights
+from repro.dist.topology import (ComponentTopology, MeshUnavailable,
+                                 make_component_mesh, make_fleet_mesh,
+                                 zipf_weights)
 from repro.serve.cluster import (MODE_DROP, MODE_FULL, MODE_STAGE1,
                                  ClusterConfig, ClusterStepBackend,
                                  allocate_budget, make_cluster_attention)
@@ -506,3 +508,24 @@ def test_cache_shared_arena_shards_identically_sharded():
   res = json.loads(line[len("RESULT:"):])
   for k, err in res.items():
     assert err == 0.0, (k, res)
+
+
+@pytest.mark.parametrize("make", [
+    lambda n, use: make_component_mesh(n, use),
+    lambda n, use: make_fleet_mesh(n, 1, use),
+], ids=["component", "fleet"])
+def test_tier_mesh_stacked_only_when_asked_or_short_cpu(make, monkeypatch):
+  """A tier runs stacked when asked (use_mesh=False) or on a CPU host
+  short of devices; asked for a mesh, or on an accelerator, a host short
+  of devices is an error naming both counts."""
+  n = len(jax.devices()) + 1
+  assert make(n, False) is None
+  assert make(n, None) is None                 # CPU host: stacked
+  with pytest.raises(MeshUnavailable, match=rf"needs {n} devices"):
+    make(n, True)
+  assert make(1, None).devices.size == 1
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  with pytest.raises(MeshUnavailable,
+                     match=rf"needs {n} devices .* found {n - 1} tpu"):
+    make(n, None)
+  assert make(n, False) is None

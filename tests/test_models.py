@@ -140,3 +140,24 @@ def test_shapes_table():
   cfg = get_config("llama3-8b")
   sp = input_specs(cfg, SHAPES["train_4k"])
   assert sp["tokens"].shape == (256, 4096)
+
+
+def test_init_params_draws_stacked_layers_in_cfg_dtype():
+  """One jitted init: every leaf in ``cfg.dtype``, the same tree for the
+  same seed, and stacked layer i holds the draw of layer i's own key."""
+  cfg = get_config("llama3-8b", smoke=True)
+  key = jax.random.PRNGKey(3)
+  params = tf.init_params(key, cfg)
+  assert {p.dtype for p in jax.tree.leaves(params)} == {jnp.dtype(cfg.dtype)}
+  again = tf.init_params(key, cfg)
+  jax.tree.map(np.testing.assert_array_equal, params, again)
+  other = tf.init_params(jax.random.PRNGKey(4), cfg)
+  assert not np.array_equal(np.asarray(params["embed"], np.float32),
+                            np.asarray(other["embed"], np.float32))
+  # init_model's key schedule: pattern position 0 draws from split 1.
+  layer_keys = jax.random.split(jax.random.split(key, 16)[1], cfg.n_blocks)
+  for i, k in enumerate(layer_keys):
+    one, _ = cm.split(tf._init_layer(k, cfg, cfg.block_pattern[0]))
+    want = jax.tree.map(lambda p: p.astype(cfg.dtype), one)
+    got = jax.tree.map(lambda p, i=i: p[i], params["blocks"]["pos0"])
+    jax.tree.map(np.testing.assert_array_equal, got, want)

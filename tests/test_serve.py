@@ -121,3 +121,36 @@ def test_decode_then_absorb_consistency(llama):
   p2 = jax.nn.softmax(lg_after.astype(jnp.float32), -1)
   tv = float(0.5 * jnp.abs(p1 - p2).sum(-1).mean())
   assert tv < 0.5
+
+
+@pytest.mark.parametrize("argv,smoke", [([], True), (["--smoke"], True),
+                                        (["--no-smoke"], False)])
+def test_serve_smoke_flag_switches(argv, smoke):
+  from repro.launch.serve import make_parser
+  assert make_parser().parse_args(argv).smoke is smoke
+
+
+def test_compile_cache_env_dir_wins(monkeypatch, tmp_path):
+  """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself: the helper
+  reports it and sets nothing."""
+  from repro.launch.compile_cache import enable_compile_cache
+  monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+  before = jax.config.jax_compilation_cache_dir
+  assert enable_compile_cache() == str(tmp_path)
+  assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+  import pathlib
+  from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,
+                                          enable_compile_cache)
+  monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+  root = pathlib.Path(__file__).resolve().parents[1]
+  assert CHECKOUT_CACHE_DIR == root / ".jax_cache"
+  assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+  before = jax.config.jax_compilation_cache_dir
+  try:
+    assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+    assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+  finally:
+    jax.config.update("jax_compilation_cache_dir", before)

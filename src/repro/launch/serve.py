@@ -148,6 +148,8 @@ def _engine_main(args):
            f"band_cov={s.get('band_cover_pct', 0.0):.0f}% "
            f"freed={s.get('freed_budget_mean', 0.0):.2f}"
            if args.contract != "deadline" else ""))
+    print(f"[engine] {name} clock_lag={s['clock_lag_ms']:.1f}ms "
+          f"compiles={s['compiles']}")
     if backend is not None and getattr(backend, "fault_stats", None) \
         and any(backend.fault_stats.values()):
       print(f"  [faults] {backend.fault_stats}")

@@ -61,6 +61,7 @@ from repro.models import common as cm
 from repro.models import transformer as tf
 from repro.serve import corpus_cache as ccache
 from repro.serve import kv_cache as kvc
+from repro.serve import spans
 from repro.serve import synopsis_kv as skv
 from repro.serve.corpus_cache import CacheConfig
 from repro.serve.prefill import make_extend_step, make_prefill_step
@@ -153,6 +154,13 @@ class EngineRequest:
   pred_loss: float = -1.0          # calibrated predicted loss at retire
   band_lo: float = 0.0             # loss confidence band (deadline_with_
   band_hi: float = 0.0             # bound / error_bounded)
+  # -- the engine's wall clock W (ServingEngine.wall_ms) -------------------
+  dispatch_w_ms: float = -1.0      # W at the dispatch of its admission
+  first_w_ms: float = -1.0         # W at its first token
+  finish_w_ms: float = -1.0        # W at its last token
+  # W - now_ms at its dispatch: host time the engine's clock had not
+  # counted when it let the request in.
+  clock_lag_ms: float = -1.0
 
   @property
   def latency_ms(self) -> float:
@@ -340,6 +348,15 @@ class ServingEngine:
     self.tok = jnp.zeros((e.n_slots, 1), jnp.int32)
     self.slots: List[Optional[_Slot]] = [None] * e.n_slots
     self.now_ms = 0.0
+    # The walls the clock advanced by that the engine measured itself
+    # (step walls, serial admission walls), and run()'s start and end on
+    # perf_counter: together they give wall_ms().
+    self.busy_ms = 0.0
+    self._t_run: Optional[float] = None
+    self._t_end: Optional[float] = None
+    # XLA programs built or fetched from the persistent cache while a
+    # run() outside warm-up was open.
+    self.compiles = 0
     self.completed: List[EngineRequest] = []
     self.events: List[Tuple[str, int, int, float]] = []
     self.step_log: List[Tuple[int, float, int]] = []   # (budget, ms, active)
@@ -415,7 +432,9 @@ class ServingEngine:
     bucket + prefill + build + the slot writes) by driving the *real*
     admit/step paths on a dummy request, so measured latencies are
     steady-state from the first trace request; warmup state is then
-    discarded and never observed by the controller.
+    discarded and never observed by the controller.  The ``compiles``
+    counter holds a window to that: it reads 0 after warm-up
+    (tests/test_spans.py::test_warm_window_compiles_nothing).
 
     Each bucket is driven TWICE, re-writing the warm slot in between:
     a step consuming a freshly *written* cache and one consuming the
@@ -468,24 +487,32 @@ class ServingEngine:
     the arena for subsequent admissions.  Warmup bypasses the cache
     entirely — its dummy all-zero prompts would otherwise alias one
     corpus and skip compiling the prefill/build programs."""
+    req.dispatch_w_ms = self.wall_ms()
+    req.clock_lag_ms = req.dispatch_w_ms - self.now_ms
     cc = self.corpus_cache
     use_cache = cc.enabled and not self._warming
     if use_cache:
-      kind, entry = cc.lookup(req.prompt, allow_extend=self._delta_ok)
+      with spans.span("engine.admit.lookup"):
+        kind, entry = cc.lookup(req.prompt, allow_extend=self._delta_ok)
       if kind == "hit":
         cc.acquire(entry, self._map_count)
         self._slot_entry[slot] = entry.key
-        return entry.first_token, self._write(cache, entry.arena, slot)
+        with spans.span("engine.admit.write"):
+          return entry.first_token, self._write(cache, entry.arena, slot)
       if kind == "extend":
-        first, new_entry = self._delta_admit(entry, req.prompt)
+        with spans.span("engine.admit.extend"):
+          first, new_entry = self._delta_admit(entry, req.prompt)
         if self._map_count > 1:       # publish holds the first mapping
           cc.acquire(new_entry, self._map_count - 1)
         self._slot_entry[slot] = new_entry.key
-        return first, self._write(cache, new_entry.arena, slot)
-    prompt = jnp.asarray(req.prompt, jnp.int32)[None]
+        with spans.span("engine.admit.write"):
+          return first, self._write(cache, new_entry.arena, slot)
     self.prefills += 1
-    logits, cache1 = self._prefill(self.params, prompt)
-    syn = self._build(cache1)
+    with spans.span("engine.admit.prefill"):
+      prompt = jnp.asarray(req.prompt, jnp.int32)[None]
+      logits, cache1 = self._prefill(self.params, prompt)
+    with spans.span("engine.admit.build"):
+      syn = self._build(cache1)
     if self._warming:
       self._warm_syn = syn       # reused to warm re-write cache lineages
     first = jnp.argmax(logits, -1).astype(jnp.int32)          # (1,)
@@ -494,8 +521,8 @@ class ServingEngine:
       if self._map_count > 1:         # publish holds the first mapping
         cc.acquire(entry, self._map_count - 1)
       self._slot_entry[slot] = entry.key
-    cache = self._write(cache, syn, slot)
-    return first, cache
+    with spans.span("engine.admit.write"):
+      return first, self._write(cache, syn, slot)
 
   def _delta_admit(self, entry, prompt) -> Tuple[jax.Array, object]:
     """Prefix-extension replay: run only the extension tokens against the
@@ -515,25 +542,29 @@ class ServingEngine:
     return first, self.corpus_cache.publish(t, arena, first)
 
   def _admit(self, req: EngineRequest, slot: int) -> None:
-    # queue_ms measures pure waiting: the clock *before* this request's
-    # own prefill+build advances it.
-    req.admit_ms = self.now_ms
-    t0 = time.perf_counter()
-    first, self.cache = self._dispatch_admission(req, slot, self.cache)
-    self.tok = self.tok.at[slot, 0].set(first[0])
-    jax.block_until_ready((self.cache, self.tok))
-    dt = (time.perf_counter() - t0) * 1e3
-    self.now_ms += dt
-    req.admit_wall_ms = dt
-    # Admission-cost EWMA: the fixed part of the demand estimate the
-    # predictive shed uses (_demand_ms).
-    if not self._warming:
-      self._admit_ms_ewma = dt if self._admit_ms_ewma == 0.0 \
-          else 0.7 * self._admit_ms_ewma + 0.3 * dt
-    req.tokens.append(int(first[0]))
-    self._slot_profile[slot] = None
-    self.slots[slot] = _Slot(req, req.max_new_tokens)
-    self.events.append(("admit", req.rid, slot, self.now_ms))
+    with spans.span("engine.admit", rid=req.rid, slot=slot, overlapped=0):
+      # queue_ms measures pure waiting: the clock *before* this request's
+      # own prefill+build advances it.
+      req.admit_ms = self.now_ms
+      t0 = time.perf_counter()
+      first, self.cache = self._dispatch_admission(req, slot, self.cache)
+      self.tok = self.tok.at[slot, 0].set(first[0])
+      with spans.span("engine.admit.sync"):
+        jax.block_until_ready((self.cache, self.tok))
+      dt = (time.perf_counter() - t0) * 1e3
+      self.now_ms += dt
+      self.busy_ms += dt
+      req.admit_wall_ms = dt
+      # Admission-cost EWMA: the fixed part of the demand estimate the
+      # predictive shed uses (_demand_ms).
+      if not self._warming:
+        self._admit_ms_ewma = dt if self._admit_ms_ewma == 0.0 \
+            else 0.7 * self._admit_ms_ewma + 0.3 * dt
+      req.tokens.append(int(first[0]))
+      self._slot_profile[slot] = None
+      self.slots[slot] = _Slot(req, req.max_new_tokens)
+      req.first_w_ms = self.wall_ms()
+      self.events.append(("admit", req.rid, slot, self.now_ms))
 
   def _pick_budget(self, active: Sequence[int],
                    extra: Sequence[EngineRequest] = ()) -> int:
@@ -595,44 +626,46 @@ class ServingEngine:
   def _retire(self, slot: int) -> None:
     s = self.slots[slot]
     req = s.req
-    req.finish_ms = self.now_ms
-    # Unpin the slot's shared-arena mapping (the entry stays resident,
-    # warm for the next admission, until capacity pressure evicts it).
-    if self._slot_entry[slot] is not None:
-      self.corpus_cache.release(self._slot_entry[slot], self._map_count)
-      self._slot_entry[slot] = None
-    req.dropped = s.remaining > 0      # shed mid-flight, not finished
-    e = self.ecfg
-    # With a cluster backend, each step reported the corpus-share-weighted
-    # accuracy of its gather (components refined / stage-1 floor / skipped).
-    stepwise = float(np.mean(req.step_acc)) if req.step_acc else None
-    if e.policy == "basic":
-      req.accuracy = stepwise if stepwise is not None else 1.0
-    elif e.policy == "partial":
-      # Partial execution: a result missing at the deadline is skipped —
-      # its entire accuracy contribution is lost (paper §5).
-      if req.dropped or req.latency_ms > self._deadline_of(req):
-        req.accuracy = 0.0
-      else:
+    with spans.span("engine.retire", rid=req.rid, slot=slot):
+      req.finish_ms = self.now_ms
+      # Unpin the slot's shared-arena mapping (the entry stays resident,
+      # warm for the next admission, until capacity pressure evicts it).
+      if self._slot_entry[slot] is not None:
+        self.corpus_cache.release(self._slot_entry[slot], self._map_count)
+        self._slot_entry[slot] = None
+      req.dropped = s.remaining > 0      # shed mid-flight, not finished
+      e = self.ecfg
+      # With a cluster backend, each step reported the corpus-share-weighted
+      # accuracy of its gather (components refined / stage-1 floor / skipped).
+      stepwise = float(np.mean(req.step_acc)) if req.step_acc else None
+      if e.policy == "basic":
         req.accuracy = stepwise if stepwise is not None else 1.0
-    elif stepwise is not None:
-      req.accuracy = stepwise
-    else:
-      # Stage 1 always landed; each step covered budget/M of the ranked
-      # clusters exactly plus the synopsis estimate of the rest.
-      fr = [min(b, self.M) / self.M for b in req.budgets] or [0.0]
-      req.accuracy = float(np.mean([self.accuracy_fn(f) for f in fr]))
-    # Contract outputs (DESIGN.md §13): the calibrated loss prediction
-    # and its confidence band, from the request's own step telemetry.
-    if self._telemetry and req.est_raw:
-      raw = float(np.mean(req.est_raw))
-      req.pred_loss = float(self.estimator.predict(raw))
-      req.band_lo, req.band_hi = self.estimator.band(
-          raw, spread=float(np.mean(req.est_spread)))
-    self._slot_profile[slot] = None
-    self.slots[slot] = None
-    self.completed.append(req)
-    self.events.append(("retire", req.rid, slot, self.now_ms))
+      elif e.policy == "partial":
+        # Partial execution: a result missing at the deadline is skipped —
+        # its entire accuracy contribution is lost (paper §5).
+        if req.dropped or req.latency_ms > self._deadline_of(req):
+          req.accuracy = 0.0
+        else:
+          req.accuracy = stepwise if stepwise is not None else 1.0
+      elif stepwise is not None:
+        req.accuracy = stepwise
+      else:
+        # Stage 1 always landed; each step covered budget/M of the ranked
+        # clusters exactly plus the synopsis estimate of the rest.
+        fr = [min(b, self.M) / self.M for b in req.budgets] or [0.0]
+        req.accuracy = float(np.mean([self.accuracy_fn(f) for f in fr]))
+      # Contract outputs (DESIGN.md §13): the calibrated loss prediction
+      # and its confidence band, from the request's own step telemetry.
+      if self._telemetry and req.est_raw:
+        raw = float(np.mean(req.est_raw))
+        req.pred_loss = float(self.estimator.predict(raw))
+        req.band_lo, req.band_hi = self.estimator.band(
+            raw, spread=float(np.mean(req.est_spread)))
+      self._slot_profile[slot] = None
+      self.slots[slot] = None
+      self.completed.append(req)
+      req.finish_w_ms = self.wall_ms()
+      self.events.append(("retire", req.rid, slot, self.now_ms))
 
   def _step_deadline(self, active: Sequence[int]) -> float:
     """Per-step deadline slice for the cluster frontend's gather decision:
@@ -650,95 +683,132 @@ class ServingEngine:
     the step itself reads the pre-admission cache — active lanes are
     identical in both — while freshly admitted lanes ride in via the
     write chain, all blocked once."""
-    if budget is None:
-      budget = self._pick_budget(active)
-    e = self.ecfg
-    plan = None
-    if self.backend is not None:
-      deadline = self._step_deadline(active) if not self._warming \
-          else float("inf")
-      plan = self.backend.plan_step(budget, deadline)
-    step = self._step_fn(budget)
-    t0 = time.perf_counter()
-    if plan is not None:
-      logits, st = step(self.params, self.cache, self.tok, plan.fe_mode)
-    else:
-      logits, st = step(self.params, self.cache, self.tok)
-    new_tok = jnp.argmax(logits, -1).astype(jnp.int32)        # (n_slots,)
-    mask = np.zeros((self.ecfg.n_slots,), bool)
-    mask[list(active)] = True
-    amask = jnp.asarray(mask)
-    target = write_cache if write_cache is not None else self.cache
-    self.cache = self._append(target, st["k_delta"], st["v_delta"],
-                              amask)
-    self.cache["pos"] = jnp.where(amask, st["pos"], self.cache["pos"])
-    # Hybrid archs: SSM decode state advances every step too (per-slot).
-    for name in ("conv_state", "ssd_state"):
-      if name in st:
-        shape = [1] * self.cache[name].ndim
-        shape[self._bx[name]] = self.ecfg.n_slots
-        m = amask.reshape(shape)
-        self.cache[name] = jnp.where(m, st[name], self.cache[name])
-    self.tok = jnp.where(amask[:, None], new_tok[:, None], self.tok)
-    jax.block_until_ready((self.cache, self.tok))
-    dt = (time.perf_counter() - t0) * 1e3
-    step_acc = None
-    step_drop = None
-    if plan is not None:
-      info = self.backend.account(budget, dt, plan, st,
-                                  warming=self._warming)
-      dt = info["parallel_ms"]       # the frontend-observed completion
-      step_acc = info["step_acc"]
-      step_drop = info.get("drop_share")
-    self.now_ms += dt
-    # With a cluster backend the shared predictor was already calibrated
-    # inside account (one predictor, one observation stream); the engine
-    # only observes its own predictor on the single-component path.
-    if self.ecfg.policy == "accuracytrader" and not self._warming \
-        and write_cache is None and self.backend is None:
-      self.controller.observe(budget, dt)
-    self.step_log.append((budget, dt, len(active)))
-    # Contract telemetry (DESIGN.md §13): the per-layer coverage
-    # profiles threaded out of the scan, averaged over layers — this
-    # step's measured signal for next step's ε decision and for each
-    # request's running raw-loss estimate.
-    prof = None
-    if self._telemetry and "est_profile" in st:
-      prof = np.asarray(st["est_profile"], np.float64)
-      prof = prof.reshape(-1, self.ecfg.n_slots, prof.shape[-1]).mean(0)
-      for i in active:
-        self._slot_profile[i] = prof[i]
-      mean_prof = prof[list(active)].mean(0)
-      self._profile_prior = mean_prof if self._profile_prior is None \
-          else 0.7 * self._profile_prior + 0.3 * mean_prof
-    toks = np.asarray(new_tok)
-    for i in active:
-      s = self.slots[i]
-      s.req.tokens.append(int(toks[i]))
-      s.req.budgets.append(budget)
-      if step_acc is not None:
-        s.req.step_acc.append(step_acc)
-      if step_drop is not None:
-        s.req.step_drop.append(step_drop)
-      if prof is not None:
-        s.req.est_raw.append(self.estimator.raw_loss(prof[i], budget))
-        s.req.est_spread.append(
-            self.estimator.spread_from_profile(prof[i], budget))
-      s.remaining -= 1
-      if s.remaining <= 0:
-        self._retire(i)
+    with spans.span("engine.decode_step", step=len(self.step_log)):
+      if budget is None:
+        with spans.span("engine.step.budget"):
+          budget = self._pick_budget(active)
+      e = self.ecfg
+      plan = None
+      if self.backend is not None:
+        deadline = self._step_deadline(active) if not self._warming \
+            else float("inf")
+        plan = self.backend.plan_step(budget, deadline)
+      step = self._step_fn(budget)
+      t0 = time.perf_counter()
+      with spans.span("engine.step.dispatch", budget=budget,
+                      active=len(active)):
+        if plan is not None:
+          logits, st = step(self.params, self.cache, self.tok, plan.fe_mode)
+        else:
+          logits, st = step(self.params, self.cache, self.tok)
+        new_tok = jnp.argmax(logits, -1).astype(jnp.int32)      # (n_slots,)
+        mask = np.zeros((self.ecfg.n_slots,), bool)
+        mask[list(active)] = True
+        amask = jnp.asarray(mask)
+        target = write_cache if write_cache is not None else self.cache
+        self.cache = self._append(target, st["k_delta"], st["v_delta"],
+                                  amask)
+        self.cache["pos"] = jnp.where(amask, st["pos"], self.cache["pos"])
+        # Hybrid archs: SSM decode state advances every step too (per-slot).
+        for name in ("conv_state", "ssd_state"):
+          if name in st:
+            shape = [1] * self.cache[name].ndim
+            shape[self._bx[name]] = self.ecfg.n_slots
+            m = amask.reshape(shape)
+            self.cache[name] = jnp.where(m, st[name], self.cache[name])
+        self.tok = jnp.where(amask[:, None], new_tok[:, None], self.tok)
+      with spans.span("engine.step.sync"):
+        jax.block_until_ready((self.cache, self.tok))
+      dt = (time.perf_counter() - t0) * 1e3
+      step_acc = None
+      step_drop = None
+      if plan is not None:
+        info = self.backend.account(budget, dt, plan, st,
+                                    warming=self._warming)
+        dt = info["parallel_ms"]       # the frontend-observed completion
+        step_acc = info["step_acc"]
+        step_drop = info.get("drop_share")
+      self.now_ms += dt
+      self.busy_ms += dt
+      # With a cluster backend the shared predictor was already calibrated
+      # inside account (one predictor, one observation stream); the engine
+      # only observes its own predictor on the single-component path.
+      if self.ecfg.policy == "accuracytrader" and not self._warming \
+          and write_cache is None and self.backend is None:
+        self.controller.observe(budget, dt)
+      self.step_log.append((budget, dt, len(active)))
+      # Contract telemetry (DESIGN.md §13): the per-layer coverage
+      # profiles threaded out of the scan, averaged over layers — this
+      # step's measured signal for next step's ε decision and for each
+      # request's running raw-loss estimate.
+      prof = None
+      if self._telemetry and "est_profile" in st:
+        prof = np.asarray(st["est_profile"], np.float64)
+        prof = prof.reshape(-1, self.ecfg.n_slots, prof.shape[-1]).mean(0)
+        for i in active:
+          self._slot_profile[i] = prof[i]
+        mean_prof = prof[list(active)].mean(0)
+        self._profile_prior = mean_prof if self._profile_prior is None \
+            else 0.7 * self._profile_prior + 0.3 * mean_prof
+      with spans.span("engine.step.tokens"):
+        toks = np.asarray(new_tok)
+        for i in active:
+          s = self.slots[i]
+          s.req.tokens.append(int(toks[i]))
+          s.req.budgets.append(budget)
+          if step_acc is not None:
+            s.req.step_acc.append(step_acc)
+          if step_drop is not None:
+            s.req.step_drop.append(step_drop)
+          if prof is not None:
+            s.req.est_raw.append(self.estimator.raw_loss(prof[i], budget))
+            s.req.est_spread.append(
+                self.estimator.spread_from_profile(prof[i], budget))
+          s.remaining -= 1
+          if s.remaining <= 0:
+            self._retire(i)
 
   # -- driving --------------------------------------------------------------
+  def wall_ms(self) -> float:
+    """W: the window's time on a server that waits for its arrivals.
+    ``now_ms`` jumps idle time to the next arrival, and advances by the
+    walls the engine measured (``busy_ms``); W adds the host time that
+    passed outside those walls since ``run()`` began, which ``now_ms``
+    never counts.  After the run it stays at the run's end."""
+    if self._t_run is None:
+      return self.now_ms
+    end = self._t_end if self._t_end is not None else time.perf_counter()
+    return self.now_ms + (end - self._t_run) * 1e3 - self.busy_ms
+
   def run(self, requests: Sequence[EngineRequest]) -> Dict[str, float]:
     """Drive the engine over an arrival trace; returns the window summary.
 
     The clock is hybrid: arrivals advance on the trace's clock, service
     advances by *measured* wall time of each dispatched program — so
     queueing delay under load is real, not modelled."""
+    self._t_run, self._t_end = time.perf_counter(), None
     pending = collections.deque(
         sorted(requests, key=lambda r: (r.arrival_ms, r.rid)))
-    if self.admission is not None:
-      return self._run_admission(pending)
+    loop = self._run_fifo if self.admission is None else self._run_admission
+
+    def count(event, _secs, **_):
+      if event == spans.COMPILE_EVENT:
+        self.compiles += 1
+
+    listen = not self._warming
+    if listen:
+      jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+      loop(pending)
+    finally:
+      self._t_end = time.perf_counter()
+      if listen:
+        jax.monitoring.unregister_event_duration_listener(count)
+    return self.summary()
+
+  def _run_fifo(self, pending) -> None:
+    """The ``run`` loop without an admission policy: arrivals admitted in
+    order as lanes free."""
     while pending or any(s is not None for s in self.slots):
       if self.ecfg.policy == "partial":
         # Partial execution sheds unfinished work AT the deadline: the
@@ -773,7 +843,6 @@ class ServingEngine:
         self.now_ms = max(self.now_ms, pending[0].arrival_ms)
         continue
       self._decode_step(active)
-    return self.summary()
 
   def _shed(self, req: EngineRequest) -> None:
     """Refuse a request at admission (predicted dead, DESIGN.md §11):
@@ -788,7 +857,7 @@ class ServingEngine:
     self.completed.append(req)
     self.events.append(("shed", req.rid, -1, self.now_ms))
 
-  def _run_admission(self, pending) -> Dict[str, float]:
+  def _run_admission(self, pending) -> None:
     """The ``run`` loop under an :class:`AdmissionPolicy` (DESIGN.md
     §11): arrivals land in a *ready* queue; each iteration rate-gates
     them (token bucket per SLO class — over-rate requests WAIT, they are
@@ -838,7 +907,6 @@ class ServingEngine:
           break
         continue
       self._decode_step(active)
-    return self.summary()
 
   def _admit_overlapped(self, admissions, active: Sequence[int]) -> None:
     """Admission/decode overlap (ROADMAP Perf): dispatch the admitted
@@ -853,16 +921,19 @@ class ServingEngine:
     cache_adm = self.cache
     firsts = []
     for req, slot in admissions:
-      req.admit_ms = t_admit
-      first, cache_adm = self._dispatch_admission(req, slot, cache_adm)
+      with spans.span("engine.admit", rid=req.rid, slot=slot, overlapped=1):
+        req.admit_ms = t_admit
+        first, cache_adm = self._dispatch_admission(req, slot, cache_adm)
       firsts.append(first)
     self._decode_step(active, budget=budget, write_cache=cache_adm)
     for (req, slot), first in zip(admissions, firsts):
-      self.tok = self.tok.at[slot, 0].set(first[0])
-      req.tokens.append(int(first[0]))
-      self._slot_profile[slot] = None
-      self.slots[slot] = _Slot(req, req.max_new_tokens)
-      self.events.append(("admit", req.rid, slot, self.now_ms))
+      with spans.span("engine.admit", rid=req.rid, slot=slot, overlapped=1):
+        self.tok = self.tok.at[slot, 0].set(first[0])
+        req.tokens.append(int(first[0]))
+        self._slot_profile[slot] = None
+        self.slots[slot] = _Slot(req, req.max_new_tokens)
+        req.first_w_ms = self.wall_ms()
+        self.events.append(("admit", req.rid, slot, self.now_ms))
 
   def _class_stats(self, reqs: Sequence[EngineRequest]) -> Dict[str, float]:
     """Accounting over one request subset; latency percentiles and
@@ -905,6 +976,10 @@ class ServingEngine:
         if self.step_log else 0.0
     s["steps"] = len(self.step_log)
     s["prefills"] = self.prefills
+    # Host time the engine's clock had not counted by the window's end
+    # (W - now_ms), and the programs compiled inside the window.
+    s["clock_lag_ms"] = self.wall_ms() - self.now_ms
+    s["compiles"] = self.compiles
     # Per-request admission wall percentiles (serial admissions only —
     # the overlapped path shares one block with the decode step and has
     # no per-request wall).  The hit-vs-miss gap here is the corpus

@@ -158,8 +158,9 @@ class EngineRequest:
   dispatch_w_ms: float = -1.0      # W at the dispatch of its admission
   first_w_ms: float = -1.0         # W at its first token
   finish_w_ms: float = -1.0        # W at its last token
-  # W - now_ms at its dispatch: host time the engine's clock had not
-  # counted when it let the request in.
+  # W - now_ms at its dispatch: host time outside the walls the engine
+  # measured.  The local run loop schedules on W, so this time delays no
+  # one; ``queue_ms`` reads the delay.
   clock_lag_ms: float = -1.0
 
   @property
@@ -545,7 +546,7 @@ class ServingEngine:
     with spans.span("engine.admit", rid=req.rid, slot=slot, overlapped=0):
       # queue_ms measures pure waiting: the clock *before* this request's
       # own prefill+build advances it.
-      req.admit_ms = self.now_ms
+      req.admit_ms = self._clock_ms()
       t0 = time.perf_counter()
       first, self.cache = self._dispatch_admission(req, slot, self.cache)
       self.tok = self.tok.at[slot, 0].set(first[0])
@@ -564,7 +565,7 @@ class ServingEngine:
       self._slot_profile[slot] = None
       self.slots[slot] = _Slot(req, req.max_new_tokens)
       req.first_w_ms = self.wall_ms()
-      self.events.append(("admit", req.rid, slot, self.now_ms))
+      self.events.append(("admit", req.rid, slot, self._clock_ms()))
 
   def _pick_budget(self, active: Sequence[int],
                    extra: Sequence[EngineRequest] = ()) -> int:
@@ -575,10 +576,10 @@ class ServingEngine:
     e = self.ecfg
     remaining = 0.0
     if e.policy == "accuracytrader":
+      now = self._clock_ms()
       remaining = min(
-          [self._abs_deadline(self.slots[i].req) - self.now_ms
-           for i in active] +
-          [self._abs_deadline(r) - self.now_ms for r in extra])
+          [self._abs_deadline(self.slots[i].req) - now for i in active] +
+          [self._abs_deadline(r) - now for r in extra])
     if self.contract == "error_bounded":
       granted, base = self.controller.budget_for_contract(
           max(remaining, 0.0),
@@ -627,7 +628,7 @@ class ServingEngine:
     s = self.slots[slot]
     req = s.req
     with spans.span("engine.retire", rid=req.rid, slot=slot):
-      req.finish_ms = self.now_ms
+      req.finish_ms = self._clock_ms()
       # Unpin the slot's shared-arena mapping (the entry stays resident,
       # warm for the next admission, until capacity pressure evicts it).
       if self._slot_entry[slot] is not None:
@@ -665,14 +666,15 @@ class ServingEngine:
       self.slots[slot] = None
       self.completed.append(req)
       req.finish_w_ms = self.wall_ms()
-      self.events.append(("retire", req.rid, slot, self.now_ms))
+      self.events.append(("retire", req.rid, slot, req.finish_ms))
 
   def _step_deadline(self, active: Sequence[int]) -> float:
     """Per-step deadline slice for the cluster frontend's gather decision:
     the most urgent resident request's remaining time, spread over its
     remaining decode steps."""
-    vals = [max(self._abs_deadline(self.slots[i].req) - self.now_ms,
-                0.0) / max(self.slots[i].remaining, 1) for i in active]
+    now = self._clock_ms()
+    vals = [max(self._abs_deadline(self.slots[i].req) - now, 0.0)
+            / max(self.slots[i].remaining, 1) for i in active]
     return min(vals) if vals else float("inf")
 
   def _decode_step(self, active: Sequence[int],
@@ -771,21 +773,45 @@ class ServingEngine:
   # -- driving --------------------------------------------------------------
   def wall_ms(self) -> float:
     """W: the window's time on a server that waits for its arrivals.
-    ``now_ms`` jumps idle time to the next arrival, and advances by the
-    walls the engine measured (``busy_ms``); W adds the host time that
-    passed outside those walls since ``run()`` began, which ``now_ms``
-    never counts.  After the run it stays at the run's end."""
+    ``now_ms`` skips idle time up to the next arrival, and advances by
+    the walls the engine measured (``busy_ms``); W adds the host time
+    that passed outside those walls since ``run()`` began, which
+    ``now_ms`` never counts.  W is the clock that decides on the local
+    path (``_clock_ms``).  After the run it stays at the run's end."""
     if self._t_run is None:
       return self.now_ms
     end = self._t_end if self._t_end is not None else time.perf_counter()
     return self.now_ms + (end - self._t_run) * 1e3 - self.busy_ms
 
+  def _clock_ms(self) -> float:
+    """The clock every scheduling decision reads: admission, budgets,
+    deadline sheds and the requests' ``admit_ms`` / ``finish_ms``.  On
+    the local path it is W, so host time outside the measured walls is
+    counted once, when it is spent, and does not pile up as admission
+    delay.  Under a cluster backend ``now_ms`` advances by the backend's
+    modelled parallel completion, where wall time means nothing, so the
+    loop keeps ``now_ms``."""
+    return self.now_ms if self.backend is not None else self.wall_ms()
+
+  def _idle_until(self, arrival_ms: float) -> None:
+    """Skip the idle time up to ``arrival_ms`` on the scheduling clock.
+    Locally ``now_ms`` moves just far enough for W to land on the
+    arrival: setting it to the arrival would leave W at the arrival plus
+    all the host time spent so far."""
+    if self.backend is not None:
+      self.now_ms = max(self.now_ms, arrival_ms)
+    else:
+      self.now_ms += max(0.0, arrival_ms - self.wall_ms())
+
   def run(self, requests: Sequence[EngineRequest]) -> Dict[str, float]:
     """Drive the engine over an arrival trace; returns the window summary.
 
-    The clock is hybrid: arrivals advance on the trace's clock, service
-    advances by *measured* wall time of each dispatched program — so
-    queueing delay under load is real, not modelled."""
+    Arrivals are on the trace's clock and service costs the *measured*
+    wall time of each dispatched program, so queueing delay under load
+    is real, not modelled.  The loop decides on ``_clock_ms``: W on the
+    local path, a server that waits for its arrivals and whose host
+    time counts; ``now_ms`` (idle skipped plus measured walls) under a
+    cluster backend."""
     self._t_run, self._t_end = time.perf_counter(), None
     pending = collections.deque(
         sorted(requests, key=lambda r: (r.arrival_ms, r.rid)))
@@ -815,14 +841,15 @@ class ServingEngine:
         # result is skipped (accuracy 0 via _retire) and the lane frees
         # for the queue — a doomed request must not keep burning steps.
         for i, s in enumerate(self.slots):
-          if s is not None and self.now_ms >= self._abs_deadline(s.req):
+          if s is not None and self._clock_ms() >= self._abs_deadline(s.req):
             self._retire(i)
       # Every arrived request that fits a free lane is admitted this
       # iteration — overlapped with the residents' decode step when
       # possible, else serially.
       free = [i for i, s in enumerate(self.slots) if s is None]
       admissions = []
-      while free and pending and pending[0].arrival_ms <= self.now_ms:
+      now = self._clock_ms()
+      while free and pending and pending[0].arrival_ms <= now:
         admissions.append((pending.popleft(), free.pop(0)))
       active = [i for i, s in enumerate(self.slots) if s is not None]
       # Overlap applies to the local single-component path only: the
@@ -839,8 +866,7 @@ class ServingEngine:
       if not active:
         if not pending:
           break
-        # Idle: jump to the next arrival.
-        self.now_ms = max(self.now_ms, pending[0].arrival_ms)
+        self._idle_until(pending[0].arrival_ms)
         continue
       self._decode_step(active)
 
@@ -850,12 +876,12 @@ class ServingEngine:
     still make its deadline.  Scores 0 accuracy and counts as dropped —
     the same book-keeping as a mid-flight partial-execution shed, minus
     all the burned work."""
-    req.finish_ms = max(self.now_ms, req.arrival_ms)
+    req.finish_ms = max(self._clock_ms(), req.arrival_ms)
     req.dropped = True
     req.shed_admission = True
     req.accuracy = 0.0
     self.completed.append(req)
-    self.events.append(("shed", req.rid, -1, self.now_ms))
+    self.events.append(("shed", req.rid, -1, req.finish_ms))
 
   def _run_admission(self, pending) -> None:
     """The ``run`` loop under an :class:`AdmissionPolicy` (DESIGN.md
@@ -869,19 +895,20 @@ class ServingEngine:
     while pending or ready or any(s is not None for s in self.slots):
       if self.ecfg.policy == "partial":
         for i, s in enumerate(self.slots):
-          if s is not None and self.now_ms >= self._abs_deadline(s.req):
+          if s is not None and self._clock_ms() >= self._abs_deadline(s.req):
             self._retire(i)
-      while pending and pending[0].arrival_ms <= self.now_ms:
+      now = self._clock_ms()
+      while pending and pending[0].arrival_ms <= now:
         ready.append(pending.popleft())
       kept, gated = [], []
       for r in ready:
-        if not self.admission.rate_admit(r, self.now_ms):
+        if not self.admission.rate_admit(r, now):
           gated.append(r)           # waits for its class's token bucket
-        elif self.admission.predicted_dead(r, self.now_ms):
+        elif self.admission.predicted_dead(r, now):
           self._shed(r)
         else:
           kept.append(r)
-      kept.sort(key=lambda r: self.admission.key(r, self.now_ms))
+      kept.sort(key=lambda r: self.admission.key(r, now))
       free = [i for i, s in enumerate(self.slots) if s is None]
       admissions = []
       while free and kept:
@@ -902,7 +929,7 @@ class ServingEngine:
           # token bucket refills (1 ms quanta keep this deterministic).
           self.now_ms += 1.0
         elif pending:
-          self.now_ms = max(self.now_ms, pending[0].arrival_ms)
+          self._idle_until(pending[0].arrival_ms)
         else:
           break
         continue
@@ -916,13 +943,12 @@ class ServingEngine:
     once for the whole window inside ``_decode_step`` — the runtime's
     async dispatch queue pipelines admission with decode instead of
     serializing a blocking admit per request."""
-    t_admit = self.now_ms
     budget = self._pick_budget(active, extra=[r for r, _ in admissions])
     cache_adm = self.cache
     firsts = []
     for req, slot in admissions:
       with spans.span("engine.admit", rid=req.rid, slot=slot, overlapped=1):
-        req.admit_ms = t_admit
+        req.admit_ms = self._clock_ms()
         first, cache_adm = self._dispatch_admission(req, slot, cache_adm)
       firsts.append(first)
     self._decode_step(active, budget=budget, write_cache=cache_adm)
@@ -933,7 +959,7 @@ class ServingEngine:
         self._slot_profile[slot] = None
         self.slots[slot] = _Slot(req, req.max_new_tokens)
         req.first_w_ms = self.wall_ms()
-        self.events.append(("admit", req.rid, slot, self.now_ms))
+        self.events.append(("admit", req.rid, slot, self._clock_ms()))
 
   def _class_stats(self, reqs: Sequence[EngineRequest]) -> Dict[str, float]:
     """Accounting over one request subset; latency percentiles and
@@ -994,8 +1020,9 @@ class ServingEngine:
                    "bytes"):
         s[f"cache_{name}"] = float(cst[name])
       s["cache_hit_rate"] = float(cst["hit_rate"])
-    s["goodput_per_s"] = s["goodput_n"] / (self.now_ms / 1e3) \
-        if self.now_ms > 0 else 0.0
+    span_ms = self._clock_ms()
+    s["goodput_per_s"] = s["goodput_n"] / (span_ms / 1e3) \
+        if span_ms > 0 else 0.0
     # Contract accounting (DESIGN.md §13): prediction quality against
     # the measured loss, band coverage at the stated confidence, and the
     # budget error_bounded freed per step vs the policy's base grant.

@@ -2,6 +2,8 @@
 invariants, deadline->budget monotonicity, xla-vs-interpret parity through
 the full engine loop, the budget-0 stage-1 floor, and the measured-latency
 delegation into the discrete-event simulator."""
+import time
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +11,7 @@ import pytest
 from repro.configs.registry import get_config
 from repro.core.deadline import BudgetController, LatencyModel
 from repro.serve import synopsis_kv as skv
+from repro.serve.cluster import ClusterConfig, ClusterStepBackend
 from repro.serve.engine import (CacheConfig, EngineConfig, EngineRequest,
                                 MeasuredStepBackend, ServingEngine,
                                 make_requests, make_zipf_requests,
@@ -186,6 +189,50 @@ def test_partial_drops_at_deadline_and_frees_lane(cfg):
   for kind, rid, slot, _ in eng.events:       # lanes still cycle cleanly
     occupied.add(slot) if kind == "admit" else occupied.discard(slot)
   assert not occupied
+
+
+@pytest.mark.parametrize("where", ["local", "cluster"])
+def test_admission_follows_the_scheduling_clock(cfg, where):
+  """Host time outside the measured walls (a 5 ms sleep in every retire)
+  does not pile up as admission delay on the local path: arrivals spaced
+  wider than a request's service find the engine idle and are let in as
+  W reaches them, and ``admit_ms`` / ``finish_ms`` are W stamps.  Under
+  a cluster backend the loop keeps scheduling on ``now_ms``, which
+  advances by the backend's modelled parallel completion."""
+  backend = ClusterStepBackend(ClusterConfig(
+      n_components=2, seed=0, use_mesh=False)) if where == "cluster" else None
+  eng = ServingEngine(cfg, EngineConfig(
+      n_slots=2, prompt_len=32, max_new_tokens=2, policy="fixed",
+      fixed_budget=1, impl="xla"), backend=backend)
+  delay_ms = 5.0
+  retire = eng._retire
+
+  def slow_retire(slot):
+    time.sleep(delay_ms / 1e3)
+    retire(slot)
+
+  eng._retire = slow_retire
+  reqs = make_requests([500.0 * i for i in range(10)], 32, 2, cfg.vocab,
+                       seed=29)
+  eng.run(reqs)
+  assert len(eng.completed) == len(reqs)
+  if where == "local":
+    step_ms = max(ms for _, ms, _ in eng.step_log)
+    for r in reqs:
+      assert r.arrival_ms <= r.dispatch_w_ms
+      assert r.dispatch_w_ms - r.arrival_ms < step_ms + delay_ms, r.rid
+      assert r.admit_ms == pytest.approx(r.dispatch_w_ms, abs=1.0)
+      assert r.finish_ms == pytest.approx(r.finish_w_ms, abs=1.0)
+    return
+  # One request resident at a time, so the step log splits into each
+  # request's own steps, at the parallel completion the backend modelled.
+  n = reqs[0].max_new_tokens
+  for k, r in enumerate(reqs):
+    assert r.admit_ms == r.arrival_ms      # the idle skip lands on it
+    steps = [ms for _, ms, _ in eng.step_log[k * n:(k + 1) * n]]
+    assert r.finish_ms == pytest.approx(
+        r.admit_ms + r.admit_wall_ms + sum(steps))
+  assert eng.now_ms == pytest.approx(reqs[-1].finish_ms)
 
 
 def test_hybrid_ssm_state_advances_per_step():

@@ -137,10 +137,10 @@ def test_wall_stamps_agree_with_the_benchmark_clock(cfg, engine):
     assert r.dispatch_w_ms == pytest.approx(clock.dispatch_w[r.rid], abs=1.0)
     assert r.first_w_ms == pytest.approx(clock.admit_w[r.rid], abs=1.0)
     assert r.finish_w_ms == pytest.approx(clock.retire_w[r.rid], abs=1.0)
-    # The lag is host time the clock had not counted: never negative, and
-    # never more than the request's whole wait, since it was let in only
-    # once the clock had passed its arrival.
-    assert 0.0 <= r.clock_lag_ms <= r.dispatch_w_ms - r.arrival_ms + 1e-9
+    # The lag is host time outside the measured walls: never negative.
+    # Admission waits for W, not now_ms, to reach the arrival.
+    assert r.clock_lag_ms >= 0.0
+    assert r.dispatch_w_ms >= r.arrival_ms - 1e-9
   assert engine.busy_ms == pytest.approx(
       sum(ms for _, ms, _ in engine.step_log)
       + sum(r.admit_wall_ms for r in reqs))

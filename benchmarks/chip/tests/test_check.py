@@ -6,6 +6,7 @@ the fp8 control, judged by the same numbers, does not; and each fault a
 served cell can have, planted under the timed path, turns ``correct``
 false.
 """
+import dataclasses
 import time
 import types
 
@@ -89,6 +90,56 @@ def test_a_sound_run_is_correct_and_the_fp8_control_is_not(tiny):
   served, low = ck["gaps"]["served"], ck["gaps"]["control"]
   assert served["tokens"] == low["tokens"] >= 30
   assert low["widest"] >= 3 * served["widest"]
+
+
+# A field beyond the base set, which arch() passes on to the reference.
+WINDOW = {"fields": {"sliding_window": "sliding_window"},
+          "sliding_window": 4096}
+
+
+def _with_window(s):
+  conf = dict(s.cell.conf, **WINDOW)
+  return dataclasses.replace(s, cell=dataclasses.replace(s.cell, conf=conf),
+                             arch=harness.arch(s.cfg, conf))
+
+
+def test_a_field_beyond_the_base_reaches_the_reference(tiny, monkeypatch):
+  seen = []
+  load = harness._load_module
+
+  def spy(path):
+    ref = load(path).Reference
+    return types.SimpleNamespace(
+        FIELDS=("sliding_window",),
+        Reference=lambda a, w, **k: seen.append((a, w)) or ref(a, w, **k))
+
+  monkeypatch.setattr(harness, "_load_module", spy)
+  s = _with_window(tiny)
+  harness.gaps(s, None, [])
+  (a, w), = seen
+  assert a["sliding_window"] == 4096 == tiny.cfg.sliding_window
+  assert w is tiny.weights
+
+
+def test_a_field_the_reference_does_not_compute_is_an_error(tiny):
+  s = _with_window(tiny)
+  # At set-up, before any weight is drawn, and again at the check.
+  with pytest.raises(ValueError, match="sliding_window"):
+    harness.setup(s.cell, SEED, time.perf_counter(), impl="xla",
+                  on_chip=False)
+  with pytest.raises(ValueError, match="sliding_window"):
+    harness.gaps(s, None, [])
+
+
+@pytest.mark.parametrize("change,field", [
+    (dict(WINDOW, sliding_window=1024), "sliding_window"),
+    ({"fields": {"use_qk_norm": "use_qk_norm"}, "use_qk_norm": True},
+     "use_qk_norm")])
+def test_a_field_the_configuration_run_does_not_match_is_an_error(
+    change, field):
+  conf = dict(_cell("fresh").conf, **change)
+  with pytest.raises(ValueError, match=field):
+    harness.model_config(conf)
 
 
 def test_only_tokens_at_full_budget_and_the_first_are_compared():

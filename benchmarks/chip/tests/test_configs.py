@@ -1,17 +1,17 @@
 """Each configuration's cut keeps its source's published widths, runs as
-its file states, and lists every key it changed."""
+its file states, and lists every key it changed.
+
+The published widths of each source's config.json are a file of their
+own, ``data/published/<config>.json``, which a configuration brings with
+it."""
 import json
+import pathlib
 
 import pytest
 
 from yardstick import harness
 
-# Published widths of each source's config.json.
-PUBLISHED = {
-    "mistral-nemo-12b-d4": {"hidden_size": 5120, "intermediate_size": 14336,
-                            "head_dim": 128, "num_attention_heads": 32,
-                            "num_key_value_heads": 8},
-}
+PUBLISHED = pathlib.Path(__file__).resolve().parent / "data" / "published"
 WIDTHS = ("hidden_size", "intermediate_size", "head_dim")
 BENCH = harness.benchmark()
 CONFIGS = {c["name"]: c for c in BENCH["configs"]}
@@ -21,14 +21,21 @@ def _file(name):
   return json.loads((harness.CHECKOUT / CONFIGS[name]["file"]).read_text())
 
 
+def _published(name):
+  path = PUBLISHED / f"{name}.json"
+  assert path.exists(), (f"configuration {name} brings its source's "
+                         f"published widths in {path}")
+  return json.loads(path.read_text())
+
+
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_the_cut_keeps_the_published_widths(name):
-  conf = _file(name)
-  for key, value in PUBLISHED[name].items():
+  conf, published = _file(name), _published(name)
+  for key, value in published.items():
     assert conf[key] == value, key
   cfg = harness.model_config(conf)
   assert (cfg.d_model, cfg.d_ff, cfg.hd) == tuple(
-      PUBLISHED[name][k] for k in WIDTHS)
+      published[k] for k in WIDTHS)
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
@@ -52,7 +59,8 @@ def test_the_file_is_what_runs(name):
 def test_every_cell_has_its_files():
   for w in BENCH["workloads"]:
     cell = harness.load_cell(w["name"], BENCH)
-    assert cell.end_to_end and "setup_s" in cell.end_to_end
+    assert {"latency_p95_ms", "ttft_p50_ms", "setup_s"} <= set(
+        cell.end_to_end), w["name"]
     assert cell.per_layer
     for m in cell.per_layer:
       assert (harness.CHIP / "metrics" / f"{m}.py").exists()

@@ -4,11 +4,17 @@ Everything that belongs to one configuration, traffic mix, cell or
 per-layer metric is a file of its own that this module finds by the name
 ``BENCHMARK.json`` gives:
 
-  configs/<config>.json     the configuration as run (see ``model_config``)
+  configs/<config>.json     the configuration as run (see ``model_config``),
+                            with the weight rules of leaves beyond the
+                            base (``weight_rules``, see ``weights.py``)
+                            and the model fields it is held to
+                            (``fields``, see ``arch``)
   traffic/<traffic>.json    the mix (see ``traffic.py``)
   cells/<workload>.json     the cell's correctness limit and sample size
   metrics/<metric>.py       a per-layer metric's reader: ``read(rec)``
-  references/<ref>.py       a configuration's plain reference
+  references/<ref>.py       a configuration's plain reference, which
+                            lists the fields beyond the base it computes
+                            (``FIELDS``, see ``reference``)
 """
 from __future__ import annotations
 
@@ -84,11 +90,12 @@ def load_cell(name: str, bench: Optional[Dict] = None) -> Cell:
 
 def model_config(conf: Dict):
   """The registry's configuration, cut as the file says, and checked
-  against the file's own numbers."""
+  against the file's own numbers: each key of ``fields`` against the
+  attribute of the configuration run that it names."""
   from repro.configs.registry import get_config  # noqa: PLC0415
 
   cfg = dataclasses.replace(get_config(conf["registry"]), **conf["replace"])
-  ours = arch(cfg)
+  ours = arch(cfg, conf)
   for key, field in conf["fields"].items():
     if conf[key] != ours[field]:
       raise ValueError(f"{conf['name']}: {key}={conf[key]} in the file, "
@@ -96,16 +103,47 @@ def model_config(conf: Dict):
   return cfg
 
 
-def arch(cfg) -> Dict:
-  """The numbers the reference and the cost functions read."""
+def _base_arch(cfg) -> Dict:
+  """The 13 numbers every reference and cost function reads."""
   return {"d_model": cfg.d_model, "n_heads": cfg.n_heads,
           "n_kv_heads": cfg.n_kv_heads, "head_dim": cfg.hd,
           "d_ff": cfg.d_ff, "vocab": cfg.vocab, "n_layers": cfg.n_layers,
-          "rope_theta": float(cfg.rope_theta), "norm_eps": float(cfg.norm_eps),
+          "rope_theta": float(cfg.rope_theta),
+          "norm_eps": float(cfg.norm_eps),
           "parallel_block": bool(cfg.parallel_block),
           "tie_embeddings": bool(cfg.tie_embeddings),
           "cluster_size": cfg.synopsis.cluster_size,
           "recent": cfg.synopsis.recent}
+
+
+def arch(cfg, conf: Dict) -> Dict:
+  """The numbers the reference and the cost functions read: the base
+  (``_base_arch``), and each other attribute of ``cfg`` that the file's ``fields``
+  name, by its name in the program (a configuration's reference reads
+  what its architecture adds there)."""
+  out = _base_arch(cfg)
+  for field in conf["fields"].values():
+    if field in out:
+      continue
+    if not hasattr(cfg, field):
+      raise ValueError(f"{conf['name']}: the file's fields name "
+                       f"{field!r}, which the configuration run lacks")
+    out[field] = getattr(cfg, field)
+  return out
+
+
+def reference(conf: Dict, cfg):
+  """The configuration's plain reference module.  It lists in ``FIELDS``
+  the fields beyond the base that it computes; a field the file names
+  beyond the base and the reference does not list is an error, since it
+  would be handed on and not acted on."""
+  mod = _load_module(CHIP / "references" / f"{conf['reference']}.py")
+  extra = set(arch(cfg, conf)) - set(_base_arch(cfg))
+  missing = sorted(extra - set(getattr(mod, "FIELDS", ())))
+  if missing:
+    raise ValueError(f"{conf['name']}: reference {conf['reference']!r} "
+                     f"does not compute the fields {missing}")
+  return mod
 
 
 class NoChip(RuntimeError):
@@ -153,10 +191,12 @@ def setup(cell: Cell, seed: int, t_start: float, impl: str = "pallas",
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
   cfg = model_config(cell.conf)
+  reference(cell.conf, cfg)
   mix = cell.mix
   shapes = jax.eval_shape(lambda k: tf.init_params(k, cfg),
                           jax.random.PRNGKey(0))
-  draws = wts.Weights(shapes, seed, cfg.dtype)
+  draws = wts.Weights(shapes, seed, cfg.dtype,
+                      rules=cell.conf.get("weight_rules"))
   params = jax.block_until_ready(draws.full())
   log(f"weights drawn at {time.perf_counter() - t_start:.1f} s")
   ecfg = EngineConfig(
@@ -179,8 +219,8 @@ def setup(cell: Cell, seed: int, t_start: float, impl: str = "pallas",
     engine.reset()
   jax.block_until_ready(engine.cache)
   log(f"set-up done at {time.perf_counter() - t_start:.1f} s")
-  return Setup(cell=cell, cfg=cfg, arch=arch(cfg), engine=engine,
-               weights=draws, corpora=pool, seed=seed,
+  return Setup(cell=cell, cfg=cfg, arch=arch(cfg, cell.conf),
+               engine=engine, weights=draws, corpora=pool, seed=seed,
                setup_s=time.perf_counter() - t_start)
 
 
@@ -286,6 +326,8 @@ def end_to_end(rec: RunRecord, setup_s: float) -> Dict[str, Dict]:
   return {
       "latency_p95_ms": {"value": stats.percentile(
           latencies(rec, rec.clock.retire_w), 95), "unit": "ms"},
+      "ttft_p50_ms": {"value": stats.percentile(
+          latencies(rec, rec.clock.admit_w), 50), "unit": "ms"},
       "ttft_p95_ms": {"value": stats.percentile(
           latencies(rec, rec.clock.admit_w), 95), "unit": "ms"},
       "tokens_per_s": {"value": done / rec.seconds, "unit": "tokens/s"},
@@ -346,7 +388,7 @@ def gaps(s: Setup, rec: RunRecord, rids: List[int],
   tokens are scored once, over the union of their compared positions."""
   import jax.numpy as jnp  # noqa: PLC0415
 
-  ref_mod = _load_module(CHIP / "references" / f"{s.cell.conf['reference']}.py")
+  ref_mod = reference(s.cell.conf, s.cfg)
   runs = {"served": ref_mod.Reference(s.arch, s.weights)}
   if control:
     runs["control"] = ref_mod.Reference(s.arch, s.weights, fp8=True)

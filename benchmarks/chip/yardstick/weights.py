@@ -15,16 +15,34 @@ its name:
   mlp.w1, w3          sd d^-1/2;  mlp.w2  sd d_ff^-1/2
   ln1, ln2, final_norm  0 (norm gains are offsets from 1)
 
-so that every projection keeps its input's scale.  A leaf of another name
-is an error: a configuration that brings one brings its rule.  Leaf i is
-drawn from ``fold_in(key, i)``; a leaf stacked over layers (under
-``blocks``) draws layer l from ``fold_in(fold_in(key, i), l)``, so that
-one layer can be drawn again alone.  Draws are float32, cast in the same
-program to the served type.
+so that every projection keeps its input's scale.  Each of these rules
+holds for a leaf of its name at the rank listed (per layer: 1 for the
+norms, 2 for ``embed``, ``unembed`` and ``mlp.*``, 3 for ``attn.*``).
+Any other leaf takes its rule from the configuration file's
+``"weight_rules"``, keyed by the leaf's name or by the end of its path
+(``"moe/w1"``: the longest key that ends the path wins), which gives one
+of
+
+  "gain"              0 (an offset from 1)
+  "fan_in"            sd shape[0]^-1/2
+  "fan_in2"           sd (shape[0] shape[1])^-1/2, as ``wo``
+  {"sd": x}           sd x, e.g. a gain drawn away from 1, so that a gain
+                      read in the wrong layout shows in the check
+
+(shapes of one layer, with no layer axis; every draw truncated at +-2
+sd).  A leaf of a base name at another rank, such as a mixture of
+experts' (E, d, f) ``moe/w1``, is not a base leaf: its rule comes from
+the file, by its path.  A file rule that names a base name, that ends
+the path of a leaf the base rules take, or that ends no leaf's path, is
+an error, and so is a leaf with no rule: a configuration that brings a
+leaf brings its rule.  Leaf i is drawn from ``fold_in(key, i)``; a leaf
+stacked over layers (under ``blocks``) draws layer l from
+``fold_in(fold_in(key, i), l)``, so that one layer can be drawn again
+alone.  Draws are float32, cast in the same program to the served type.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,22 +56,48 @@ def key_from_seed(seed: int) -> jax.Array:
                                   impl="threefry2x32")
 
 
-def _std(name: str, shape: Tuple[int, ...], tied: bool) -> float:
-  """Standard deviation of a leaf of one layer (no layer axis)."""
-  if name in ("ln1", "ln2", "final_norm"):
+# The base leaves' rules and the rank (of one layer's leaf) each holds
+# for; ``embed`` has its own (see ``_base_std``).
+BASE_RULES = {"ln1": ("gain", 1), "ln2": ("gain", 1),
+              "final_norm": ("gain", 1), "unembed": ("fan_in", 2),
+              "wq": ("fan_in", 3), "wk": ("fan_in", 3), "wv": ("fan_in", 3),
+              "wo": ("fan_in2", 3), "w1": ("fan_in", 2),
+              "w3": ("fan_in", 2), "w2": ("fan_in", 2)}
+
+
+def _rule_std(rule, shape: Tuple[int, ...]) -> float:
+  if rule == "gain":
     return 0.0
-  if name == "embed":
+  if rule == "fan_in" and len(shape) >= 1:
+    return shape[0] ** -0.5
+  if rule == "fan_in2" and len(shape) >= 2:
+    return (shape[0] * shape[1]) ** -0.5
+  if isinstance(rule, dict) and set(rule) == {"sd"} and \
+      type(rule["sd"]) in (int, float) and rule["sd"] >= 0:
+    return float(rule["sd"])
+  raise ValueError(f"weight rule {rule!r} does not apply to shape {shape}: "
+                   'one of "gain", "fan_in", "fan_in2", {"sd": x >= 0}')
+
+
+def _base_std(name: str, shape: Tuple[int, ...],
+              tied: bool) -> Optional[float]:
+  """Standard deviation of a base leaf of one layer (no layer axis), or
+  None where the base rules do not take the leaf."""
+  if name == "embed" and len(shape) == 2:
     # A tied table is also the unembedding, and takes its scale.
     return shape[1] ** -0.5 if tied else 1.0
-  if name in ("unembed", "wq", "wk", "wv", "w1", "w3", "w2"):
-    return shape[0] ** -0.5
-  if name == "wo":
-    return (shape[0] * shape[1]) ** -0.5
-  raise KeyError(f"no weight rule for leaf {name!r} {shape}")
+  if name in BASE_RULES and BASE_RULES[name][1] == len(shape):
+    return _rule_std(BASE_RULES[name][0], shape)
+  return None
 
 
-def _one(key, name: str, shape, dtype, tied: bool = False):
-  std = _std(name, shape, tied)
+def _file_key(names, rules: Dict) -> Optional[str]:
+  """The longest key of ``rules`` that ends the leaf's path."""
+  tails = ("/".join(names[-k:]) for k in range(len(names), 0, -1))
+  return next((t for t in tails if t in rules), None)
+
+
+def _one(key, std: float, shape, dtype):
   if std == 0.0:
     return jnp.zeros(shape, dtype)
   x = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
@@ -63,16 +107,42 @@ def _one(key, name: str, shape, dtype, tied: bool = False):
 class Weights:
   """The seed's weights for a tree of shapes (ShapeDtypeStruct leaves)."""
 
-  def __init__(self, shapes: Any, seed: int, dtype):
+  def __init__(self, shapes: Any, seed: int, dtype,
+               rules: Optional[Dict] = None):
+    rules = rules or {}
+    base = sorted(set(rules) & (set(BASE_RULES) | {"embed"}))
+    if base:
+      raise ValueError(f"weight_rules may not override the base leaves' "
+                       f"rules: {base}")
     flat, self.treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    self.specs = []
-    for i, (path, leaf) in enumerate(flat):
-      names = [p.key for p in path]
-      self.specs.append((i, names, tuple(leaf.shape), names[0] == "blocks"))
+    names = [[p.key for p in path] for path, _ in flat]
+    self.tied = not any(n[-1] == "unembed" for n in names)
+    # (index, names, shape, stacked, sd of one layer's leaf); a stacked
+    # leaf's layer has its shape without the leading layer axis.
+    self.specs, used = [], set()
+    for i, (n, (_, leaf)) in enumerate(zip(names, flat)):
+      shape, stacked = tuple(leaf.shape), n[0] == "blocks"
+      layer = shape[1:] if stacked else shape
+      std = _base_std(n[-1], layer, self.tied and not stacked)
+      key = _file_key(n, rules)
+      if std is not None and key is not None:
+        raise ValueError(f"weight_rules[{key!r}] names {'/'.join(n)}, a "
+                         f"base leaf: its rule may not be overridden")
+      if std is None:
+        if key is None:
+          raise KeyError(f"no weight rule for leaf {'/'.join(n)} {layer}: "
+                         'the configuration file gives it under '
+                         '"weight_rules", by its name or the end of its '
+                         'path')
+        std = _rule_std(rules[key], layer)
+        used.add(key)
+      self.specs.append((i, n, shape, stacked, std))
+    unused = sorted(set(rules) - used)
+    if unused:
+      raise ValueError(f"weight_rules give no leaf of the tree its rule: "
+                       f"{unused}")
     self.key = key_from_seed(seed)
     self.dtype = dtype
-    self.tied = not any(names[-1] == "unembed" for _, names, _, _ in
-                        self.specs)
     self._layers: Dict[int, Dict] = {}
 
   def full(self) -> Any:
@@ -81,14 +151,14 @@ class Weights:
     @jax.jit
     def draw(key):
       out = []
-      for i, names, shape, stacked in self.specs:
+      for i, _, shape, stacked, std in self.specs:
         k = jax.random.fold_in(key, i)
         if stacked:
-          out.append(jax.vmap(lambda l, k=k, n=names[-1], s=shape[1:]: _one(
-              jax.random.fold_in(k, l), n, s, self.dtype))(
+          out.append(jax.vmap(lambda l, k=k, s=shape[1:], std=std: _one(
+              jax.random.fold_in(k, l), std, s, self.dtype))(
                   jnp.arange(shape[0])))
         else:
-          out.append(_one(k, names[-1], shape, self.dtype, self.tied))
+          out.append(_one(k, std, shape, self.dtype))
       return out
 
     return jax.tree_util.tree_unflatten(self.treedef, draw(self.key))
@@ -99,9 +169,9 @@ class Weights:
 
     @jax.jit
     def draw(key):
-      return {names[-1]: _one(jax.random.fold_in(key, i), names[-1], shape,
-                              self.dtype, self.tied)
-              for i, names, shape, _ in specs}
+      return {names[-1]: _one(jax.random.fold_in(key, i), std, shape,
+                              self.dtype)
+              for i, names, shape, _, std in specs}
 
     return draw(self.key)
 
@@ -112,9 +182,9 @@ class Weights:
       return self._layers[layer]
     specs = [s for s in self.specs if s[3]]
     out: Dict = {}
-    for (i, names, shape, _), val in zip(specs, _draw_layer(
-        self.key, jnp.int32(layer), tuple((i, names[-1], shape[1:])
-                                          for i, names, shape, _ in specs),
+    for (_, names, _, _, _), val in zip(specs, _draw_layer(
+        self.key, jnp.int32(layer),
+        tuple((i, std, shape[1:]) for i, _, shape, _, std in specs),
         self.dtype)):
       node = out
       for n in names[2:-1]:
@@ -125,8 +195,8 @@ class Weights:
 
 
 def _draw_layer_impl(key, layer, specs, dtype):
-  return [_one(jax.random.fold_in(jax.random.fold_in(key, i), layer), name,
-               shape, dtype) for i, name, shape in specs]
+  return [_one(jax.random.fold_in(jax.random.fold_in(key, i), layer), std,
+               shape, dtype) for i, std, shape in specs]
 
 
 _draw_layer = jax.jit(_draw_layer_impl, static_argnums=(2, 3))
